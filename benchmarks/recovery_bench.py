@@ -32,6 +32,7 @@ import numpy as np
 
 from benchmarks.common import emit
 from repro.core import sketch as sk
+from repro.launch.mesh import make_mesh
 from repro.serving.recovery import DurableSketchEngine, recover
 from repro.serving.sketch_engine import SketchServeEngine, SketchTopKEndpoint
 from repro.streams import zipf_hh_workload
@@ -134,11 +135,11 @@ def recovery_remesh_latency() -> None:
     spec = sk.mod_sketch_spec(stream.schema, [(0,), (1,)], (512, 512), 4)
     ladder = [n for n in (1, 2, 4, 8) if n <= n_dev]
     for src, dst in zip(ladder, ladder[1:]):
-        svc = ShardedTopKService(spec, _KEY, jax.make_mesh((src,), ("data",)),
+        svc = ShardedTopKService(spec, _KEY, make_mesh((src,), ("data",)),
                                  sync_every=4)
         for it, fr in blocks[:12]:
             svc.ingest(it, fr)
-        dst_mesh = jax.make_mesh((dst,), ("data",))
+        dst_mesh = make_mesh((dst,), ("data",))
         t0 = time.perf_counter()
         svc.remesh(dst_mesh)
         jax.block_until_ready([st.table for st in svc.merged.states])
@@ -146,7 +147,7 @@ def recovery_remesh_latency() -> None:
         emit("recovery/remesh", dt * 1e6,
              f"src={src};dst={dst};devices={n_dev}")
         # and back down: shrink is the failure-response direction
-        src_mesh = jax.make_mesh((src,), ("data",))
+        src_mesh = make_mesh((src,), ("data",))
         t0 = time.perf_counter()
         svc.remesh(src_mesh)
         jax.block_until_ready([st.table for st in svc.merged.states])

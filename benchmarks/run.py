@@ -26,6 +26,8 @@ def main() -> None:
 
     import jax
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import bounds_check, common, grad_compression_bench, \
         hierarchy_ingest_bench, kernel_microbench, migrate_bench, \
         paper_figs, recovery_bench, roofline_report, serve_bench, \

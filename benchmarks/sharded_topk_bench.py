@@ -25,6 +25,7 @@ import numpy as np
 
 from benchmarks.common import emit
 from repro.core import sketch as sk
+from repro.launch.mesh import make_mesh
 from repro.serving.sharded_topk import ShardedTopKService
 from repro.streams import zipf_hh_workload
 
@@ -47,7 +48,7 @@ def sharded_ingest_throughput() -> None:
     counts = [c for c in (1, 2, 4, 8) if c <= jax.device_count()]
     edges = _block_edges(len(items))
     for c in counts:
-        mesh = jax.make_mesh((c,), ("data",))
+        mesh = make_mesh((c,), ("data",))
         svc = ShardedTopKService(spec, jax.random.PRNGKey(0), mesh,
                                  sync_every=None)
         # warmup: compile the per-shard fold for this shard count
@@ -72,7 +73,7 @@ def sharded_sync_cadence() -> None:
     wl, spec = _workload()
     items, freqs = wl.stream.items, wl.stream.freqs
     c = max(c for c in (1, 2, 4, 8) if c <= jax.device_count())
-    mesh = jax.make_mesh((c,), ("data",))
+    mesh = make_mesh((c,), ("data",))
     edges = _block_edges(len(items))
     for cadence in (1, 4, _BLOCKS):
         svc = ShardedTopKService(spec, jax.random.PRNGKey(0), mesh,
@@ -94,7 +95,7 @@ def sharded_query_after_sync() -> None:
     """End-to-end: topk served from the merged tables (descent included)."""
     wl, spec = _workload()
     c = max(cc for cc in (1, 2, 4, 8) if cc <= jax.device_count())
-    mesh = jax.make_mesh((c,), ("data",))
+    mesh = make_mesh((c,), ("data",))
     svc = ShardedTopKService(spec, jax.random.PRNGKey(0), mesh)
     svc.ingest(wl.stream.items, wl.stream.freqs)
     t0 = time.perf_counter()

@@ -29,6 +29,7 @@ import jax
 import numpy as np
 
 from repro.core import sketch as sk
+from repro.launch.mesh import make_mesh
 from repro.serving.faults import FaultPlan, ServingSupervisor
 from repro.serving.sharded_topk import ShardedTopKService
 from repro.serving.sketch_engine import SketchTopKEndpoint
@@ -76,12 +77,12 @@ with tempfile.TemporaryDirectory() as d:
           f"bit-identical")
 
 # --- 4: elastic 2 -> 4 shard remesh mid-stream --------------------------
-svc = ShardedTopKService(spec, key, jax.make_mesh((2,), ("data",)),
+svc = ShardedTopKService(spec, key, make_mesh((2,), ("data",)),
                          sync_every=4)
 half = len(ops) // 2
 for _, it, fr in ops[:half]:
     svc.ingest(it, fr)
-svc.remesh(jax.make_mesh((4,), ("data",)))
+svc.remesh(make_mesh((4,), ("data",)))
 for _, it, fr in ops[half:]:
     svc.ingest(it, fr)
 ids, est = svc.topk(10)

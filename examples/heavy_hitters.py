@@ -8,7 +8,9 @@ above a frequency threshold by recursive descent -- comparing the batched
 Pallas candidate kernel against the jnp reference and against exact ground
 truth, then serves top-k through the SketchTopKEndpoint -- and finally
 through the sharded service, whose output is bit-identical at any shard
-count (the forced 8-device CPU mesh below stands in for real hardware).
+count.  Off a TPU it forces 8 virtual CPU devices for the meshes and runs
+the kernels in interpret mode; on the chip, ``python chip_smoke.py
+[--chips 4]`` drives the same serving path with the kernels compiled.
 """
 import os
 if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
@@ -21,6 +23,7 @@ import numpy as np
 
 from repro.core import hierarchy as hh
 from repro.core import sketch as sk
+from repro.launch.mesh import make_mesh
 from repro.serving.engine import SketchTopKEndpoint
 from repro.streams import ngram_hh_workload, zipf_hh_workload
 
@@ -80,8 +83,8 @@ print(f"conservative top-10:       {est_cons.tolist()} (<= linear per key)")
 # (different block splits!) yields bit-identical level tables and top-k --
 # the psum merge of linear tables is exact, so shard count cannot matter
 from repro.serving.sharded_topk import ShardedTopKService
-svc1 = ShardedTopKService(spec, key, jax.make_mesh((1,), ("data",)))
-svc4 = ShardedTopKService(spec, key, jax.make_mesh((4,), ("data",)),
+svc1 = ShardedTopKService(spec, key, make_mesh((1,), ("data",)))
+svc4 = ShardedTopKService(spec, key, make_mesh((4,), ("data",)),
                           sync_every=2)
 svc1.ingest(wl.stream.items, wl.stream.freqs)
 third = len(wl.stream.items) // 3

@@ -2,8 +2,10 @@
 
     PYTHONPATH=src python examples/sharded_serving.py
 
-Simulates the serving deployment on a forced 8-device CPU mesh (swap in a
-real TPU mesh via repro.launch.mesh.make_production_mesh on hardware):
+Runs the serving deployment on an 8-device mesh built by
+repro.launch.mesh.make_mesh -- 8 virtual CPU devices off a TPU (kernels in
+interpret mode; ``python chip_smoke.py --chips 4`` is the on-chip sharded
+path):
 
   1. a single-shard SketchTopKEndpoint handles early traffic,
   2. traffic grows, so the endpoint is promoted in place to a
@@ -26,6 +28,7 @@ import jax
 import numpy as np
 
 from repro.core import sketch as sk
+from repro.launch.mesh import make_mesh
 from repro.serving.engine import SketchTopKEndpoint
 from repro.serving.sharded_topk import ShardedTopKService
 from repro.streams import zipf_hh_workload
@@ -43,7 +46,7 @@ print(f"endpoint: ingested {ep.total:,} of {wl.stream.total:,} occurrences")
 
 # phase 2: promote to an 8-shard service on the mesh; conservative
 # endpoints would be refused here (non-linear tables cannot psum)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 svc = ep.to_sharded(mesh, sync_every=4)
 print(f"promoted to {svc.n_shards} shards over axes {svc.data_axes}")
 
@@ -66,7 +69,7 @@ assert exact <= got
 
 # verification: a 1-shard service over the identical stream agrees bit-
 # for-bit -- linear tables + exact integer psum make sharding invisible
-ref = ShardedTopKService(spec, key, jax.make_mesh((1,), ("data",)))
+ref = ShardedTopKService(spec, key, make_mesh((1,), ("data",)))
 ref.ingest(items, freqs)
 for a, b in zip(svc.state().states, ref.state().states):
     assert np.array_equal(np.asarray(a.table), np.asarray(b.table))

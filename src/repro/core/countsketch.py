@@ -340,20 +340,21 @@ def candidate_estimates(
     *,
     use_kernel: bool = False,
     interpret: Optional[bool] = None,
-    tile_h: int = 512,
     max_batch: Optional[int] = None,
 ) -> np.ndarray:
     """Median signed estimates for every (prefix x value) child: f32[P, C].
 
-    ``use_kernel=True`` routes the per-row gather through the signed Pallas
-    grid kernel (kernels/hier_query.hier_candidate_query_signed); the
-    default is the jnp reference.  Both agree bit-for-bit on int32 tables
-    (the kernel's two-limb gather only covers int32; other dtypes always
-    take the reference path).  ``max_batch`` chunks the prefix axis only,
-    like hierarchy.candidate_estimates.
+    ``use_kernel=True`` routes the per-row gather through the Pallas gather
+    kernel (kernels/hier_query.hier_candidate_query_signed); the default
+    is the jnp reference.  Both agree bit-for-bit on int32 tables; the
+    kernel path refuses any other dtype (use the reference).
+    ``max_batch`` chunks the prefix axis only, like
+    hierarchy.candidate_estimates.
     """
     prefixes = jnp.asarray(np.asarray(prefixes, dtype=np.uint32))
     values = jnp.asarray(np.asarray(values, dtype=np.uint32))
+    if use_kernel:
+        state = hh._one_replica(state)
     pp, cp, sp, sc = candidate_signed_partials(hspec, state.params, level,
                                                prefixes, values)
     table = state.tables[level]
@@ -361,13 +362,12 @@ def candidate_estimates(
         hier_candidate_query_signed,
         hier_candidate_query_signed_ref,
     )
-    if use_kernel and table.dtype == jnp.int32:
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+    if use_kernel:
+        hh._check_kernel_dtype(table)
+
         def one(pp_chunk, sp_chunk):
             per_row = hier_candidate_query_signed(
-                table, pp_chunk, cp, sp_chunk, sc, tile_h=tile_h,
-                interpret=interpret)
+                table, pp_chunk, cp, sp_chunk, sc, interpret=interpret)
             return jnp.median(per_row, axis=0)
     else:
         def one(pp_chunk, sp_chunk):

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import sketch as sk
 
 
@@ -201,15 +201,14 @@ def merge_local_tables(
     """psum-merge the lazily accumulated per-device tables."""
 
     def m(tbl_l):
-        return jax.lax.psum(tbl_l[0], data_axes)[None]
+        return jax.lax.psum(tbl_l[0], data_axes)
 
+    # the psum result is the same on every shard: a replicated output
     fn = shard_map(
-        m, mesh=mesh, in_specs=(P(data_axes),), out_specs=P(data_axes),
+        m, mesh=mesh, in_specs=(P(data_axes),), out_specs=P(),
         check_vma=False,
     )
-    merged = fn(local_tables)
-    # every shard now holds the global table; take shard 0's copy
-    return merged[0]
+    return fn(local_tables)
 
 
 def lazy_hierarchy_update(
@@ -231,10 +230,10 @@ def lazy_hierarchy_update(
     (core.hierarchy.hierarchy_indices -- exact under the shared per-group
     params every ``init_hierarchy`` state carries), and scatter-adds into
     its local copy of every level's table.  The psum merge is deferred to
-    the explicit sync point (:func:`merge_local_hierarchy`).  On TPU the
-    per-device fold body is a drop-in for the fused one-launch Pallas
-    kernel (kernels/hier_update.py); the jnp body is bit-identical to it
-    by the parity tests.
+    the explicit sync point (:func:`merge_local_hierarchy`).  The
+    per-device fold body is the jnp scatter-add, not the fused Pallas
+    kernel (kernels/hier_update.py), on every backend; the two are
+    bit-identical by the parity tests.
 
     ``params`` keeps the one-entry-per-level shape of ``HierarchyState``
     for compatibility; the cascade only reads the finest level's entry

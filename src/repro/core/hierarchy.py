@@ -524,6 +524,41 @@ def candidate_partials(
     return pp, cp
 
 
+def _pad_rows_pow2(a: np.ndarray) -> np.ndarray:
+    """Zero-pad the leading axis to the next power of two, so the jitted
+    partial hashes and query launches compile O(log P) shapes rather than
+    one per surviving-prefix count (prefix 0 is a valid key; pad rows are
+    sliced off by the callers)."""
+    n = a.shape[0]
+    n_pad = 1 << max(0, (n - 1).bit_length())
+    return a if n_pad == n else np.pad(a, ((0, n_pad - n), (0, 0)))
+
+
+def _check_kernel_dtype(table: jax.Array) -> None:
+    if table.dtype != jnp.int32:
+        raise ValueError(
+            f"use_kernel=True needs an int32 table (got {table.dtype}): "
+            "use the jnp reference path (use_kernel=False)")
+
+
+def _one_replica(state):
+    """``state`` on ONE device, for the Pallas descent kernels.
+
+    XLA cannot partition a Mosaic kernel over a mesh, so arrays replicated
+    over several devices (the sharded service's psum-merged tables) are
+    read from their first replica, without a copy; arrays on one device
+    pass through."""
+    def one(x):
+        if not isinstance(x, jax.Array) or len(x.sharding.device_set) == 1:
+            return x
+        if not x.sharding.is_fully_replicated:
+            raise ValueError(
+                "use_kernel=True reads the tables on one device: merge a "
+                "sharded table (psum) before the descent")
+        return x.addressable_shards[0].data
+    return jax.tree_util.tree_map(one, state)
+
+
 def candidate_estimates(
     hspec: HierarchySpec,
     state: HierarchyState,
@@ -533,43 +568,46 @@ def candidate_estimates(
     *,
     use_kernel: bool = False,
     interpret: Optional[bool] = None,
-    tile_h: int = 512,
     max_batch: Optional[int] = None,
 ) -> np.ndarray:
     """CM estimates for every (prefix x candidate-value) child: [P, C].
 
-    ``use_kernel=True`` routes through the Pallas one-launch grid kernel
+    ``use_kernel=True`` routes through the Pallas gather kernel
     (kernels/hier_query.py); the default is the jnp gather reference.  Both
-    agree bit-for-bit on int32 tables.  The kernel's two-limb gather only
-    covers int32, so other table dtypes (int64 hierarchies) always take
-    the dtype-preserving reference path.
+    agree bit-for-bit.  The kernel covers int32 tables; any other dtype
+    with ``use_kernel=True`` is refused rather than silently served by
+    the reference.
 
     ``max_batch`` bounds the per-call P*C working set: the partial hashes
     are computed ONCE for all prefixes and candidates, then only the
     prefix axis is chunked (the child partials are identical across
     chunks, so rehashing them per chunk would be pure waste).
     """
-    prefixes = jnp.asarray(np.asarray(prefixes, dtype=np.uint32))
+    prefixes = np.asarray(prefixes, dtype=np.uint32)
+    p = prefixes.shape[0]
+    prefixes = jnp.asarray(_pad_rows_pow2(prefixes))
     values = jnp.asarray(np.asarray(values, dtype=np.uint32))
+    if use_kernel:
+        state = _one_replica(state)
     pp, cp = candidate_partials(hspec, state, level, prefixes, values)
     table = state.states[level].table
     from repro.kernels.hier_query import (
         hier_candidate_query,
         hier_candidate_query_ref,
     )
-    if use_kernel and table.dtype == jnp.int32:
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+    if use_kernel:
+        _check_kernel_dtype(table)
+
         def one(pp_chunk):
-            return hier_candidate_query(table, pp_chunk, cp, tile_h=tile_h,
+            return hier_candidate_query(table, pp_chunk, cp,
                                         interpret=interpret)
     else:
         def one(pp_chunk):
             return hier_candidate_query_ref(table, pp_chunk, cp)
 
-    p, c = pp.shape[1], cp.shape[1]
-    if max_batch is None or p * c <= max_batch:
-        return np.asarray(one(pp))
+    p_all, c = pp.shape[1], cp.shape[1]
+    if max_batch is None or p_all * c <= max_batch:
+        return np.asarray(one(pp))[:p]
     p_chunk = max(1, max_batch // max(c, 1))
     outs = []
     for s in range(0, p, p_chunk):
@@ -656,16 +694,16 @@ def batched_candidate_estimates(
     *,
     use_kernel: bool = False,
     interpret: Optional[bool] = None,
-    tile_h: int = 512,
     max_batch: Optional[int] = None,
 ) -> List[np.ndarray]:
     """CM estimate grids for Q concurrent requests at one level: Q x [P_q, C].
 
     All requests share the level's candidate set but carry their own
     surviving prefix sets.  The prefix partials are hashed ONCE over the
-    concatenated prefixes, padded to a common P_max (pad prefix index 0 is
-    always a valid cell; the padded rows are sliced off), and the whole
-    [Q, P_max, C] request grid is evaluated in a single launch
+    concatenated prefixes, padded to a common power-of-two P_max (pad
+    prefix index 0 is always a valid cell; the padded rows are sliced
+    off), and the whole [Q, P_max, C] request grid is evaluated in a
+    single launch
     (kernels/hier_query.hier_candidate_query_batched) -- Q concurrent
     queries cost one ``pallas_call`` per level instead of Q.  Every
     returned cell is computed lane-independently, so each request's grid
@@ -681,22 +719,26 @@ def batched_candidate_estimates(
         raise ValueError("every request must have a non-empty prefix set "
                          "(callers retire empty requests before batching)")
     values = jnp.asarray(np.asarray(values, dtype=np.uint32))
-    cat = jnp.asarray(np.concatenate(
-        [np.asarray(p, dtype=np.uint32) for p in prefix_sets], axis=0))
-    pp_all, cp = candidate_partials(hspec, state, level, cat, values)
-    nq, p_max, c = len(counts), max(counts), int(cp.shape[1])
+    if use_kernel:
+        state = _one_replica(state)
+    cat = np.concatenate(
+        [np.asarray(p, dtype=np.uint32) for p in prefix_sets], axis=0)
+    pp_all, cp = candidate_partials(hspec, state, level,
+                                    jnp.asarray(_pad_rows_pow2(cat)), values)
+    # pad P_max to a power of two too: one compiled launch per bucket
+    nq, c = len(counts), int(cp.shape[1])
+    p_max = 1 << max(0, (max(counts) - 1).bit_length())
 
     table = state.states[level].table
     from repro.kernels.hier_query import (
         hier_candidate_query_batched,
         hier_candidate_query_batched_ref,
     )
-    if use_kernel and table.dtype == jnp.int32:
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+    if use_kernel:
+        _check_kernel_dtype(table)
+
         def one(pp3):
             return hier_candidate_query_batched(table, pp3, cp,
-                                                tile_h=tile_h,
                                                 interpret=interpret)
     else:
         def one(pp3):

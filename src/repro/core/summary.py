@@ -96,10 +96,16 @@ class SpaceSaving:
         heapq.heapify(self._heap)
 
     def values(self) -> np.ndarray:
-        """All summarized rows: uint32[K, n_cols] (admission order arbitrary)."""
+        """All summarized rows: uint32[K, n_cols], sorted lexicographically.
+
+        The canonical order makes descent answers (and their tie order)
+        independent of admission order, so surfaces that hold the same
+        rows -- one pool, or shard pools folded into one -- answer
+        identically."""
         if not self._count:
             return np.zeros((0, self.n_cols), dtype=np.uint32)
-        return np.asarray(list(self._count), dtype=np.uint32)
+        rows = np.asarray(list(self._count), dtype=np.uint32)
+        return rows[np.lexsort(rows.T[::-1])]
 
     def counts(self) -> Dict[Row, float]:
         return dict(self._count)

@@ -1,8 +1,8 @@
-"""Pallas TPU kernels for the sketch hot path (linear update = one-hot MXU
-matmul, conservative update = VMEM-resident sequential min/max, signed
-update = sign-weighted one-hot MXU matmul, query = one-hot gather + row
-reduce), with jnp oracles in ref.py and jitd wrappers in ops.py.
-Validated in interpret mode on CPU; set interpret=False on TPU."""
+"""Pallas TPU kernels for the sketch hot path (linear and signed update =
+fused one-hot MXU fold over every hierarchy level, query = per-row cell
+gather + row reduce, conservative update = interpret-only sequential
+min/max), with jnp oracles in ref.py and jitted wrappers in ops.py.
+Compiled on a TPU, interpreted elsewhere (ops.default_interpret)."""
 from repro.kernels.hashes import IndexPlan, make_plan  # noqa: F401
 from repro.kernels.hier_query import (  # noqa: F401
     hier_candidate_query,

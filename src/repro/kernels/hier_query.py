@@ -5,107 +5,56 @@ surviving prefixes by C candidate values of the next module group and needs
 a Count-Min estimate for every child.  The mixed-radix cell address is
 separable -- ``idx(p, c) = pp[k, p] + cp[k, c]`` per row k, with the prefix
 partial pre-scaled by the last group's range and the child partial's stride
-equal to 1 -- so the kernel takes the two partial-index factors and
-evaluates the full P x C grid without ever materializing the P*C key
-matrix or rehashing anything in-kernel.
-
-Per (row k, range tile t): form the child indices for the whole grid,
-one-hot them against the tile's lanes, and gather via an MXU contraction
-exactly like kernels/sketch_query.py -- table values split into two 16-bit
-limbs so the f32 matmuls are exact for int32 counts.  The (w, P*C) per-row
-estimates accumulate across tiles by output revisiting; the final Count-Min
-min over the w rows is a VPU reduce fused into the jit'd wrapper.
-
-Grid = (w, h_pad / TILE_H); one launch per descent level.
+equal to 1 -- so the wrapper forms the ``[w, P*C]`` child indices from the
+two partial-index factors without rehashing anything, and one
+kernels/sketch_query.gather_rows launch reads every child's cell in every
+row (exact for any int32 count, O(P*C) work per row).  The final Count-Min
+min (or, signed, the +-1 sign product) is a VPU op fused into the jitted
+wrapper.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from repro.kernels.sketch_query import gather_rows
 
 
-def _hier_kernel(tile_h: int, pp_ref, cp_ref, tlo_ref, thi_ref, out_ref):
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    pp = pp_ref[0]                                            # int32[P]
-    cp = cp_ref[0]                                            # int32[C]
-    p, c = pp.shape[0], cp.shape[0]
-    idx = (pp[:, None] + cp[None, :]).reshape(p * c)          # int32[P*C]
-    local = idx - t * tile_h
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (p * c, tile_h), 1)
-    onehot = (local[:, None] == lanes).astype(jnp.float32)    # [P*C, TH]
-    glo = jnp.dot(onehot, tlo_ref[0][:, None],
-                  preferred_element_type=jnp.float32)         # [P*C, 1]
-    ghi = jnp.dot(onehot, thi_ref[0][:, None],
-                  preferred_element_type=jnp.float32)
-    val = glo.astype(jnp.int32) + (ghi.astype(jnp.int32) << 16)
-    out_ref[...] = out_ref[...] + val[:, 0][None, :]
+def _child_indices(pp: jax.Array, cp: jax.Array) -> jax.Array:
+    w = pp.shape[0]
+    return (pp.astype(jnp.int32)[:, :, None]
+            + cp.astype(jnp.int32)[:, None, :]).reshape(w, -1)
 
 
-@functools.partial(jax.jit, static_argnames=("tile_h", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def hier_candidate_query(
-    table: jax.Array,   # int32[w, h] (padded internally to tile_h)
+    table: jax.Array,   # int32[w, h]
     pp: jax.Array,      # uint32[w, P] prefix partial indices (pre-scaled)
     cp: jax.Array,      # uint32[w, C] child partial indices (stride 1)
     *,
-    tile_h: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Count-Min estimates for every (prefix, candidate) child: int32[P, C].
 
-    The two-limb gather assumes cell counts fit int32; other table dtypes
-    must take :func:`hier_candidate_query_ref`.
+    Other table dtypes must take :func:`hier_candidate_query_ref`.
     """
     if table.dtype != jnp.int32:
         raise ValueError(
             f"hier_candidate_query supports int32 tables only (got "
             f"{table.dtype}); use hier_candidate_query_ref")
-    w, h = table.shape
-    h_pad = ((h + tile_h - 1) // tile_h) * tile_h
-    if h_pad != h:
-        # padding cells are zero and no child index reaches them (< h)
-        table = jnp.pad(table, ((0, 0), (0, h_pad - h)))
-    n_tiles = h_pad // tile_h
-    p = pp.shape[1]
-    c = cp.shape[1]
-    grid = (w, n_tiles)
-
-    ti = table.astype(jnp.int32)
-    tlo = (ti & jnp.int32(0xFFFF)).astype(jnp.float32)
-    thi = ((ti >> 16) & jnp.int32(0xFFFF)).astype(jnp.float32)
-
-    per_row = pl.pallas_call(
-        functools.partial(_hier_kernel, tile_h),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, p), lambda k, t: (k, 0)),
-            pl.BlockSpec((1, c), lambda k, t: (k, 0)),
-            pl.BlockSpec((1, tile_h), lambda k, t: (k, t)),
-            pl.BlockSpec((1, tile_h), lambda k, t: (k, t)),
-        ],
-        out_specs=pl.BlockSpec((1, p * c), lambda k, t: (k, 0)),
-        out_shape=jax.ShapeDtypeStruct((w, p * c), jnp.int32),
-        interpret=interpret,
-    )(pp.astype(jnp.int32), cp.astype(jnp.int32), tlo, thi)
-    return jnp.min(per_row, axis=0).reshape(p, c)
+    per_row = gather_rows(table, _child_indices(pp, cp), interpret=interpret)
+    return jnp.min(per_row, axis=0).reshape(pp.shape[1], cp.shape[1])
 
 
 @jax.jit
 def hier_candidate_query_ref(table: jax.Array, pp: jax.Array,
                              cp: jax.Array) -> jax.Array:
-    """Pure-jnp oracle: same signature minus tiling, [P, C] in the table's
-    dtype (unlike the kernel it is exact for int64 / float tables too)."""
-    w = table.shape[0]
-    idx = (pp.astype(jnp.int32)[:, :, None]
-           + cp.astype(jnp.int32)[:, None, :]).reshape(w, -1)
-    vals = jnp.take_along_axis(table, idx, axis=1)
+    """Pure-jnp oracle: same signature, [P, C] in the table's dtype (unlike
+    the kernel it is exact for int64 / float tables too)."""
+    vals = jnp.take_along_axis(table, _child_indices(pp, cp), axis=1)
     return jnp.min(vals, axis=0).reshape(pp.shape[1], cp.shape[1])
 
 
@@ -117,45 +66,20 @@ def hier_candidate_query_ref(table: jax.Array, pp: jax.Array,
 # factors: the sign of child (p, c) at row k is ``sp[k, p] * sc[k, c]``
 # (cumulative parities XOR, so +-1 signs multiply), computed OUTSIDE the
 # kernel by core.countsketch.candidate_signed_partials exactly like the
-# bucket partials.  The kernel gathers the exact int32 cell value and
-# multiplies by the +-1 product in int32; the median over rows is the
-# wrapper's caller's reduce (rows are returned so the estimator stays
+# bucket partials.  The kernel gathers the exact int32 cell value and the
+# wrapper multiplies by the +-1 product in int32; the median over rows is
+# the caller's reduce (rows are returned so the estimator stays
 # bit-comparable to the jnp reference).
 
-def _hier_kernel_signed(tile_h: int, pp_ref, cp_ref, sp_ref, sc_ref,
-                        tlo_ref, thi_ref, out_ref):
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    pp = pp_ref[0]                                            # int32[P]
-    cp = cp_ref[0]                                            # int32[C]
-    p, c = pp.shape[0], cp.shape[0]
-    idx = (pp[:, None] + cp[None, :]).reshape(p * c)          # int32[P*C]
-    local = idx - t * tile_h
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (p * c, tile_h), 1)
-    onehot = (local[:, None] == lanes).astype(jnp.float32)    # [P*C, TH]
-    glo = jnp.dot(onehot, tlo_ref[0][:, None],
-                  preferred_element_type=jnp.float32)         # [P*C, 1]
-    ghi = jnp.dot(onehot, thi_ref[0][:, None],
-                  preferred_element_type=jnp.float32)
-    val = glo.astype(jnp.int32) + (ghi.astype(jnp.int32) << 16)
-    sgn = (sp_ref[0][:, None] * sc_ref[0][None, :]).reshape(p * c)
-    out_ref[...] = out_ref[...] + (val[:, 0] * sgn)[None, :]
-
-
-@functools.partial(jax.jit, static_argnames=("tile_h", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def hier_candidate_query_signed(
-    table: jax.Array,   # int32[w, h] (padded internally to tile_h)
+    table: jax.Array,   # int32[w, h]
     pp: jax.Array,      # uint32[w, P] prefix partial indices (pre-scaled)
     cp: jax.Array,      # uint32[w, C] child partial indices (stride 1)
     sp: jax.Array,      # +-1[w, P] prefix sign partials
     sc: jax.Array,      # +-1[w, C] child sign partials
     *,
-    tile_h: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Per-row signed estimates for every (prefix, candidate) child:
     int32[w, P, C].  The caller takes the median over rows (float); keeping
@@ -164,36 +88,11 @@ def hier_candidate_query_signed(
         raise ValueError(
             f"hier_candidate_query_signed supports int32 tables only (got "
             f"{table.dtype}); use hier_candidate_query_signed_ref")
-    w, h = table.shape
-    h_pad = ((h + tile_h - 1) // tile_h) * tile_h
-    if h_pad != h:
-        # padding cells are zero and no child index reaches them (< h)
-        table = jnp.pad(table, ((0, 0), (0, h_pad - h)))
-    n_tiles = h_pad // tile_h
-    p = pp.shape[1]
-    c = cp.shape[1]
-    grid = (w, n_tiles)
-
-    tlo = (table & jnp.int32(0xFFFF)).astype(jnp.float32)
-    thi = ((table >> 16) & jnp.int32(0xFFFF)).astype(jnp.float32)
-
-    per_row = pl.pallas_call(
-        functools.partial(_hier_kernel_signed, tile_h),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, p), lambda k, t: (k, 0)),
-            pl.BlockSpec((1, c), lambda k, t: (k, 0)),
-            pl.BlockSpec((1, p), lambda k, t: (k, 0)),
-            pl.BlockSpec((1, c), lambda k, t: (k, 0)),
-            pl.BlockSpec((1, tile_h), lambda k, t: (k, t)),
-            pl.BlockSpec((1, tile_h), lambda k, t: (k, t)),
-        ],
-        out_specs=pl.BlockSpec((1, p * c), lambda k, t: (k, 0)),
-        out_shape=jax.ShapeDtypeStruct((w, p * c), jnp.int32),
-        interpret=interpret,
-    )(pp.astype(jnp.int32), cp.astype(jnp.int32),
-      sp.astype(jnp.int32), sc.astype(jnp.int32), tlo, thi)
-    return per_row.reshape(w, p, c)
+    w, p, c = pp.shape[0], pp.shape[1], cp.shape[1]
+    vals = gather_rows(table, _child_indices(pp, cp), interpret=interpret)
+    sgn = (sp.astype(jnp.int32)[:, :, None]
+           * sc.astype(jnp.int32)[:, None, :])
+    return vals.reshape(w, p, c) * sgn
 
 
 @jax.jit
@@ -204,10 +103,8 @@ def hier_candidate_query_signed_ref(table: jax.Array, pp: jax.Array,
     for int32 tables; dtype-preserving gather, sign applied in float)."""
     w = table.shape[0]
     p, c = pp.shape[1], cp.shape[1]
-    idx = (pp.astype(jnp.int32)[:, :, None]
-           + cp.astype(jnp.int32)[:, None, :]).reshape(w, -1)
-    vals = jnp.take_along_axis(table, idx, axis=1).astype(jnp.float32)
-    vals = vals.reshape(w, p, c)
+    vals = jnp.take_along_axis(table, _child_indices(pp, cp), axis=1)
+    vals = vals.astype(jnp.float32).reshape(w, p, c)
     return vals * sp.astype(jnp.float32)[:, :, None] \
         * sc.astype(jnp.float32)[:, None, :]
 
@@ -216,29 +113,27 @@ def hier_candidate_query_signed_ref(table: jax.Array, pp: jax.Array,
 # Request axis: Q concurrent queries in the one launch
 # --------------------------------------------------------------------------
 #
-# The grid evaluates P*C independent lanes per (row, tile); nothing ties a
-# lane to "one query", so Q concurrent requests' prefix sets ride the lane
-# axis: [w, Q, P] prefix partials flatten to [w, Q*P], the SAME pallas_call
-# runs once with Q*P*C lanes, and the output folds back to [Q, P, C].
-# Each lane's estimate is computed independently (one-hot gather + min over
-# rows), so every request's [P, C] slab is bit-identical to its own
-# single-request launch -- batching Q queries costs one launch per level
-# instead of Q (the sketch serving engine's batched descent).
+# Nothing ties a lane to "one query", so Q concurrent requests' prefix sets
+# ride the lane axis: [w, Q, P] prefix partials flatten to [w, Q*P], the
+# SAME launch runs once with Q*P*C lanes, and the output folds back to
+# [Q, P, C].  Each lane's estimate is computed independently, so every
+# request's [P, C] slab is bit-identical to its own single-request launch
+# -- batching Q queries costs one launch per level instead of Q (the
+# sketch serving engine's batched descent).
 
-@functools.partial(jax.jit, static_argnames=("tile_h", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def hier_candidate_query_batched(
     table: jax.Array,   # int32[w, h]
     pp: jax.Array,      # uint32[w, Q, P] per-request prefix partials
     cp: jax.Array,      # uint32[w, C] child partials (shared by all requests)
     *,
-    tile_h: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Count-Min estimates for Q requests' (prefix, candidate) grids:
-    int32[Q, P, C], one ``pallas_call`` total."""
+    int32[Q, P, C], one kernel launch total."""
     w, q, p = pp.shape
     flat = hier_candidate_query(table, pp.reshape(w, q * p), cp,
-                                tile_h=tile_h, interpret=interpret)
+                                interpret=interpret)
     return flat.reshape(q, p, cp.shape[1])
 
 

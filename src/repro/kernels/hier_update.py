@@ -1,41 +1,44 @@
 """Pallas TPU kernel: fold one stream block into ALL hierarchy levels in a
 single launch.
 
-Hierarchy ingest used to run per level -- L hash passes and L kernel
-launches per stream block (that path survives only as the parity
-reference, core.hierarchy.update_reference).  Under the shared per-group
-hash family (core/hierarchy.py) the level indices nest in the mixed radix,
+Under the shared per-group hash family (core/hierarchy.py) the level
+indices nest in the mixed radix,
 
     idx_L = idx_finest // (r_{L+1} * ... * r_{m-1}),
 
 so one composite hash per (row, item) determines every level's cell.  This
 kernel concatenates the levels into one padded table ``[w, sum_L h_L_pad]``
 (each level padded to a tile multiple) and runs ONE pallas_call with grid
-(w, total_tiles):
+``(total_tiles,)``; every step updates one ``[w, tile_h]`` column tile of
+all w rows:
 
-  * at each row's first tile the full composite index is hashed once into a
-    VMEM scratch (uint32 limb CW arithmetic on the VPU, exactly
-    kernels/hashes.row_indices);
-  * every tile then derives ITS level's local index with one integer
-    division by the tile's static level divisor and a subtraction of the
-    tile's base column -- the per-tile metadata rides in a tiny
-    ``[n_tiles, 2]`` int32 input indexed by the grid;
-  * the scatter-add reuses the one-hot MXU limb-matmul machinery of
-    kernels/sketch_update.py verbatim: frequencies split into two 12-bit
-    limbs so integer tables accumulate exactly (per-arrival |f| < 2^24,
-    wrapper-checked upstream), f32 tables use a single contraction.
+  * at the first tile every row's per-group hashes are evaluated once
+    (uint32 limb CW arithmetic on the VPU, kernels/hashes.level_indices
+    with the hash parameters read as SMEM scalars) and each level's index
+    is cached in a ``[n_levels, w, B]`` VMEM scratch -- the level index is
+    the Horner prefix of the group sub-indices, so no division is needed;
+  * every tile derives its level and base column from the grid index
+    (static tile offsets per level) and reads its level's cached indices;
+  * the scatter-add is a one-hot MXU contraction: ``onehot[j, b] =
+    (idx_b == base + j)`` over the B lanes, contracted with an ``[8, B]``
+    frequency operand.  Integer tables split f into three bf16-exact limbs
+    (8, 8 and a signed top limb of the arithmetic split, each |limb| <= 256)
+    so every f32 partial sum is an exact integer (per-arrival |f| < 2^24,
+    wrapper-checked upstream); float32 tables contract f itself at HIGHEST
+    precision.
 
-Versus L per-level launches this amortizes the chunk/frequency loads and
-the B x tile one-hot materialization across levels, hashes each item once
-per row instead of once per (row, level), and dispatches once.  The
+The flat single-level sketch update (kernels/sketch_update.py) is the same
+kernel on a one-level plan.  The signed (Count-Sketch) fold adds a second
+scratch with the packed cumulative sign-parity bits and multiplies the
+frequency operand by each level's +-1 sign before the contraction.  The
 conservative update is excluded (its row-coupling min forces a sequential
-B-loop; it gets the shared cascade at the index level via
-core.hierarchy.update_conservative instead).
+B-loop; see kernels/sketch_update_conservative.py).
 
-Bit-exactness: identical to per-level core.sketch.update on integer tables;
-for f32 tables exact whenever every per-cell partial sum is exactly
-representable (e.g. integer-valued weights < 2^24), tolerance-level
-otherwise (MXU accumulation order differs from scatter order).
+Bit-exactness: identical to per-level core.sketch.update on integer tables
+(int32 wrap included); for f32 tables exact whenever every per-cell
+partial sum is exactly representable (e.g. integer-valued weights < 2^24),
+tolerance-level otherwise (MXU accumulation order differs from scatter
+order).
 
 See docs/architecture.md ("Fused Pallas ingest") for where this kernel
 sits in the ingest dataflow.
@@ -43,40 +46,42 @@ sits in the ingest dataflow.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.hashes import (
     IndexPlan,
+    all_indices,
+    level_indices,
     make_plan,
-    row_indices,
     row_sign_bits,
+    sign_bits,
     signs_from_bits,
 )
 
-_LIMB_BITS = 12
-_LIMB_MASK = (1 << _LIMB_BITS) - 1
+_LANES = 128          # stream blocks are padded to a lane multiple
+_LHS_ROWS = 8         # frequency operand rows (one f32 sublane tile)
 
 
 class HierPlan(NamedTuple):
     """Static layout of the fused multi-level update (hashable, jit-static).
 
-    ``plan`` is the FINEST level's IndexPlan (group-major chunk layout);
-    every coarser level's index is plan's composite index divided by its
-    ``level_divs`` entry.  Level l's table occupies columns
-    ``[level_offsets[l], level_offsets[l] + level_sizes[l])`` of the
-    concatenated table, zero-padded up to ``level_pads[l]`` (a tile_h
-    multiple)."""
+    ``plan`` is the FINEST level's IndexPlan (group-major chunk layout).
+    Level l is indexed by groups ``0 .. level_groups[l] - 1``; equivalently
+    its index is the finest composite index divided by ``level_divs[l]``.
+    Level l's table occupies columns ``[level_offsets[l], level_offsets[l]
+    + level_sizes[l])`` of the concatenated table, zero-padded up to
+    ``level_pads[l]`` (a tile_h multiple)."""
     plan: IndexPlan
     level_sizes: Tuple[int, ...]    # h_l (unpadded cells per row)
     level_pads: Tuple[int, ...]     # h_l padded to a tile_h multiple
     level_divs: Tuple[int, ...]     # idx_l = idx_finest // div_l
     tile_h: int
+    level_groups: Tuple[int, ...]   # groups indexing each level
 
     @property
     def n_levels(self) -> int:
@@ -98,6 +103,11 @@ class HierPlan(NamedTuple):
     def n_tiles(self) -> int:
         return self.padded_cols // self.tile_h
 
+    @property
+    def sign_bits(self) -> Tuple[int, ...]:
+        """Bit of the packed cumulative parities holding each level's sign."""
+        return tuple(g - 1 for g in self.level_groups)
+
 
 def make_hier_plan(hspec, tile_h: int = 512) -> HierPlan:
     """Build the fused-update plan from a core.hierarchy.HierarchySpec."""
@@ -111,70 +121,142 @@ def make_hier_plan(hspec, tile_h: int = 512) -> HierPlan:
         level_pads=pads,
         level_divs=tuple(int(d) for d in hspec.level_divisors),
         tile_h=int(tile_h),
+        level_groups=tuple(range(1, hspec.n_levels + 1)),
     )
 
 
-def _tile_meta(hplan: HierPlan) -> np.ndarray:
-    """int32[n_tiles, 2]: (level divisor, tile's base column within its
-    level) per global tile -- the only per-tile state the kernel needs."""
-    rows = []
-    for l, pad in enumerate(hplan.level_pads):
-        for t in range(pad // hplan.tile_h):
-            rows.append((hplan.level_divs[l], t * hplan.tile_h))
-    return np.asarray(rows, dtype=np.int32)
+def flat_plan(plan: IndexPlan, h_pad: int, tile_h: int) -> HierPlan:
+    """One-level plan: the flat composite sketch ``[w, h_pad]`` table."""
+    if h_pad % tile_h:
+        raise ValueError(
+            f"padded table width {h_pad} not a multiple of {tile_h}")
+    return HierPlan(plan=plan, level_sizes=(plan.table_size,),
+                    level_pads=(int(h_pad),), level_divs=(1,),
+                    tile_h=int(tile_h),
+                    level_groups=(len(plan.group_cols),))
 
 
-def _local_lanes(idx_scratch_ref, meta_ref):
-    """Derive this tile's local one-hot targets from the cached finest
-    index: cascade division by the tile's level divisor, then shift by the
-    tile's base column.  Out-of-tile items (and zero-pad rows of the block)
-    simply match no lane."""
-    idx_fine = idx_scratch_ref[...]                          # int32[B]
-    div = meta_ref[0, 0]
-    base = meta_ref[0, 1]
-    return jax.lax.div(idx_fine, div) - base
+def _tile_level(hplan: HierPlan, t):
+    """(level, base column within the level) of global tile ``t``, from the
+    static per-level tile offsets (scalar arithmetic on the grid index)."""
+    level = jnp.int32(0)
+    skipped = jnp.int32(0)
+    start = 0
+    for l, pad in enumerate(hplan.level_pads[:-1]):
+        start += pad // hplan.tile_h
+        past = (t >= start).astype(jnp.int32)
+        level = level + past
+        skipped = skipped + past * (pad // hplan.tile_h)
+    return level, (t - skipped) * hplan.tile_h
 
 
-def _hier_kernel_int(hplan: HierPlan, tile_h: int,
-                     chunks_ref, flo_ref, fhi_ref, q_ref, r_ref, meta_ref,
-                     table_in_ref, table_out_ref, idx_scratch_ref):
-    """One (row, global tile) step: int table, two 12-bit frequency limbs."""
-    t = pl.program_id(1)
+def _fold_kernel(hplan: HierPlan, signed: bool, int_table: bool, *refs):
+    """One column tile of all w rows."""
+    if signed:
+        (chunks_ref, f_ref, q_ref, r_ref, sq_ref, sr_ref,
+         table_in_ref, table_out_ref, idx_ref, bits_ref) = refs
+    else:
+        (chunks_ref, f_ref, q_ref, r_ref,
+         table_in_ref, table_out_ref, idx_ref) = refs
+    plan, tile_h = hplan.plan, hplan.tile_h
+    t = pl.program_id(0)
 
-    @pl.when(t == 0)
-    def _hash_once():
-        # ONE composite hash per (row, item), cached for all tiles/levels
-        idx_scratch_ref[...] = row_indices(
-            hplan.plan, chunks_ref[...], q_ref[0], r_ref[0])
-
-    local = _local_lanes(idx_scratch_ref, meta_ref)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (local.shape[0], tile_h), 1)
-    onehot = (local[:, None] == lanes).astype(jnp.float32)    # [B, TH]
-    dot_lo = jnp.dot(flo_ref[...][None, :], onehot,
-                     preferred_element_type=jnp.float32)      # [1, TH]
-    dot_hi = jnp.dot(fhi_ref[...][None, :], onehot,
-                     preferred_element_type=jnp.float32)
-    delta = dot_lo.astype(jnp.int32) + (dot_hi.astype(jnp.int32) << _LIMB_BITS)
-    table_out_ref[...] = table_in_ref[...] + delta
-
-
-def _hier_kernel_f32(hplan: HierPlan, tile_h: int,
-                     chunks_ref, f_ref, q_ref, r_ref, meta_ref,
-                     table_in_ref, table_out_ref, idx_scratch_ref):
-    """float32-table variant (gradient sketches): single MXU contraction."""
-    t = pl.program_id(1)
+    def chunk(c):
+        return chunks_ref[c : c + 1, :]                       # uint32[1, B]
 
     @pl.when(t == 0)
     def _hash_once():
-        idx_scratch_ref[...] = row_indices(
-            hplan.plan, chunks_ref[...], q_ref[0], r_ref[0])
+        for k in range(plan.width):
+            levels = level_indices(plan, hplan.level_groups, chunk,
+                                   lambda c: q_ref[k, c],
+                                   lambda j: r_ref[k, j])
+            for l, idx in enumerate(levels):
+                idx_ref[l, k : k + 1, :] = idx
+            if signed:
+                bits_ref[k : k + 1, :] = sign_bits(
+                    plan, chunk, lambda c: sq_ref[k, c],
+                    lambda j: sr_ref[k, j])
 
-    local = _local_lanes(idx_scratch_ref, meta_ref)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (local.shape[0], tile_h), 1)
-    onehot = (local[:, None] == lanes).astype(jnp.float32)
-    delta = jnp.dot(f_ref[...][None, :], onehot,
-                    preferred_element_type=jnp.float32)
-    table_out_ref[...] = table_in_ref[...] + delta[0][None, :]
+    level, base = _tile_level(hplan, t)
+    lhs = f_ref[...]                                          # [8, B]
+    b = lhs.shape[1]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (tile_h, b), 0)
+    if signed:
+        bit = jnp.int32(0)
+        for l, sb in enumerate(hplan.sign_bits):
+            bit = bit + (level == l).astype(jnp.int32) * sb
+    for k in range(plan.width):
+        local = idx_ref[level, k : k + 1, :] - base           # int32[1, B]
+        lhs_k = lhs
+        if signed:
+            par = (bits_ref[k : k + 1, :] >> bit) & 1
+            lhs_k = lhs * (1 - 2 * par).astype(lhs.dtype)
+        onehot = (lanes == local).astype(lhs.dtype)           # [TH, B]
+        d = jax.lax.dot_general(
+            lhs_k, onehot, (((1,), (1,)), ((), ())),
+            precision=None if int_table else jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)               # [8, TH]
+        if int_table:
+            delta = (d[0:1].astype(jnp.int32)
+                     + (d[1:2].astype(jnp.int32) << 8)
+                     + (d[2:3].astype(jnp.int32) << 16))
+        else:
+            delta = d[0:1]
+        table_out_ref[k : k + 1, :] = table_in_ref[k : k + 1, :] + delta
+
+
+def fold(hplan: HierPlan, table: jax.Array, chunks: jax.Array,
+         freqs: jax.Array, params: Tuple[jax.Array, ...],
+         interpret: Optional[bool]) -> jax.Array:
+    """Shared launcher of the fused fold (call inside a jit).
+
+    ``params`` is ``(q, r)`` or, signed, ``(q, r, sign_q, sign_r)``."""
+    from repro.kernels.ops import resolve_interpret
+
+    w, cols = table.shape
+    if cols != hplan.padded_cols:
+        raise ValueError(
+            f"concatenated table has {cols} columns, plan expects "
+            f"{hplan.padded_cols}")
+    signed = len(params) == 4
+    int_table = jnp.issubdtype(table.dtype, jnp.integer)
+    b, c = chunks.shape
+    b_pad = -(-b // _LANES) * _LANES
+    if b_pad != b:
+        # zero-frequency pad rows are no-ops (their cells are valid)
+        chunks = jnp.pad(chunks, ((0, b_pad - b), (0, 0)))
+        freqs = jnp.pad(freqs, (0, b_pad - b))
+    if int_table:
+        fi = freqs.astype(jnp.int32)
+        rows = [fi & 0xFF, (fi >> 8) & 0xFF, fi >> 16]        # exact in bf16
+        lhs_dtype = jnp.bfloat16
+    else:
+        rows = [freqs.astype(jnp.float32)]
+        lhs_dtype = jnp.float32
+    lhs = jnp.zeros((_LHS_ROWS, b_pad), lhs_dtype).at[: len(rows)].set(
+        jnp.stack(rows).astype(lhs_dtype))
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda t: (0,) * len(shape))
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    tbl_spec = pl.BlockSpec((w, hplan.tile_h), lambda t: (0, t))
+    scratch = [pltpu.VMEM((hplan.n_levels, w, b_pad), jnp.int32)]
+    if signed:
+        scratch.append(pltpu.VMEM((w, b_pad), jnp.int32))
+    in_specs = ([whole((c, b_pad)), whole((_LHS_ROWS, b_pad))]
+                + [smem] * len(params) + [tbl_spec])
+    return pl.pallas_call(
+        functools.partial(_fold_kernel, hplan, signed, int_table),
+        grid=(hplan.n_tiles,),
+        in_specs=in_specs,
+        out_specs=tbl_spec,
+        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
+        scratch_shapes=scratch,
+        input_output_aliases={len(in_specs) - 1: 0},
+        interpret=resolve_interpret(interpret),
+        name="hier_update",
+    )(chunks.T, lhs, *(p.astype(jnp.int32) for p in params), table)
 
 
 @functools.partial(
@@ -182,13 +264,13 @@ def _hier_kernel_f32(hplan: HierPlan, tile_h: int,
 )
 def hier_update_pallas(
     hplan: HierPlan,
-    table: jax.Array,    # [w, hplan.padded_cols] int or float32 concat table
+    table: jax.Array,    # [w, hplan.padded_cols] int32 or float32 concat table
     chunks: jax.Array,   # uint32[B, C] finest-layout 16-bit key digits
     freqs: jax.Array,    # int32[B] or float32[B]
     q: jax.Array,        # uint32[w, C] shared-family multipliers
     r: jax.Array,        # uint32[w, m] shared-family offsets
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fold one stream block into every level's table in ONE pallas_call.
 
@@ -196,121 +278,7 @@ def hier_update_pallas(
     rows of the block are no-ops (freq 0); level pad columns are never hit
     (indices < h_l).
     """
-    w, cols = table.shape
-    if cols != hplan.padded_cols:
-        raise ValueError(
-            f"concatenated table has {cols} columns, plan expects "
-            f"{hplan.padded_cols}")
-    tile_h = hplan.tile_h
-    b, c = chunks.shape
-    grid = (w, hplan.n_tiles)
-    meta = jnp.asarray(_tile_meta(hplan))
-
-    chunk_spec = pl.BlockSpec((b, c), lambda k, t: (0, 0))
-    f_spec = pl.BlockSpec((b,), lambda k, t: (0,))
-    q_spec = pl.BlockSpec((1, c), lambda k, t: (k, 0))
-    r_spec = pl.BlockSpec((1, r.shape[1]), lambda k, t: (k, 0))
-    meta_spec = pl.BlockSpec((1, 2), lambda k, t: (t, 0))
-    tbl_spec = pl.BlockSpec((1, tile_h), lambda k, t: (k, t))
-    scratch = [pltpu.VMEM((b,), jnp.int32)]
-
-    if jnp.issubdtype(table.dtype, jnp.integer):
-        flo = (freqs.astype(jnp.int32) & _LIMB_MASK).astype(jnp.float32)
-        fhi = (freqs.astype(jnp.int32) >> _LIMB_BITS).astype(jnp.float32)
-        kernel = functools.partial(_hier_kernel_int, hplan, tile_h)
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[chunk_spec, f_spec, f_spec, q_spec, r_spec, meta_spec,
-                      tbl_spec],
-            out_specs=tbl_spec,
-            out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-            scratch_shapes=scratch,
-            input_output_aliases={6: 0},
-            interpret=interpret,
-        )(chunks, flo, fhi, q, r, meta, table)
-    else:
-        kernel = functools.partial(_hier_kernel_f32, hplan, tile_h)
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[chunk_spec, f_spec, q_spec, r_spec, meta_spec,
-                      tbl_spec],
-            out_specs=tbl_spec,
-            out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-            scratch_shapes=scratch,
-            input_output_aliases={5: 0},
-            interpret=interpret,
-        )(chunks, freqs.astype(table.dtype), q, r, meta, table)
-
-
-# --------------------------------------------------------------------------
-# Signed (Count-Sketch) fused hierarchy fold
-# --------------------------------------------------------------------------
-#
-# Same single-launch cascade with a second VMEM scratch: the packed
-# cumulative sign-parity bits (kernels/hashes.row_sign_bits) are hashed once
-# per row alongside the finest index, and each tile reads ITS level's sign
-# as one bit -- the metadata grows a third column carrying the tile's level
-# index.  The sign multiplies the frequency limbs before the MXU
-# contraction, exactly as in kernels/sketch_update.py's signed kernels.
-
-def _tile_meta_signed(hplan: HierPlan) -> np.ndarray:
-    """int32[n_tiles, 3]: (level divisor, tile base column, level index)."""
-    rows = []
-    for l, pad in enumerate(hplan.level_pads):
-        for t in range(pad // hplan.tile_h):
-            rows.append((hplan.level_divs[l], t * hplan.tile_h, l))
-    return np.asarray(rows, dtype=np.int32)
-
-
-def _hier_kernel_signed_int(hplan: HierPlan, tile_h: int,
-                            chunks_ref, flo_ref, fhi_ref, q_ref, r_ref,
-                            sq_ref, sr_ref, meta_ref,
-                            table_in_ref, table_out_ref,
-                            idx_scratch_ref, bits_scratch_ref):
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _hash_once():
-        idx_scratch_ref[...] = row_indices(
-            hplan.plan, chunks_ref[...], q_ref[0], r_ref[0])
-        bits_scratch_ref[...] = row_sign_bits(
-            hplan.plan, chunks_ref[...], sq_ref[0], sr_ref[0])
-
-    local = _local_lanes(idx_scratch_ref, meta_ref)
-    s = signs_from_bits(bits_scratch_ref[...], meta_ref[0, 2])  # f32[B]
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (local.shape[0], tile_h), 1)
-    onehot = (local[:, None] == lanes).astype(jnp.float32)      # [B, TH]
-    dot_lo = jnp.dot((s * flo_ref[...])[None, :], onehot,
-                     preferred_element_type=jnp.float32)        # [1, TH]
-    dot_hi = jnp.dot((s * fhi_ref[...])[None, :], onehot,
-                     preferred_element_type=jnp.float32)
-    delta = dot_lo.astype(jnp.int32) + (dot_hi.astype(jnp.int32) << _LIMB_BITS)
-    table_out_ref[...] = table_in_ref[...] + delta
-
-
-def _hier_kernel_signed_f32(hplan: HierPlan, tile_h: int,
-                            chunks_ref, f_ref, q_ref, r_ref,
-                            sq_ref, sr_ref, meta_ref,
-                            table_in_ref, table_out_ref,
-                            idx_scratch_ref, bits_scratch_ref):
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _hash_once():
-        idx_scratch_ref[...] = row_indices(
-            hplan.plan, chunks_ref[...], q_ref[0], r_ref[0])
-        bits_scratch_ref[...] = row_sign_bits(
-            hplan.plan, chunks_ref[...], sq_ref[0], sr_ref[0])
-
-    local = _local_lanes(idx_scratch_ref, meta_ref)
-    s = signs_from_bits(bits_scratch_ref[...], meta_ref[0, 2])
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (local.shape[0], tile_h), 1)
-    onehot = (local[:, None] == lanes).astype(jnp.float32)
-    delta = jnp.dot((s * f_ref[...])[None, :], onehot,
-                    preferred_element_type=jnp.float32)
-    table_out_ref[...] = table_in_ref[...] + delta[0][None, :]
+    return fold(hplan, table, chunks, freqs, (q, r), interpret)
 
 
 @functools.partial(
@@ -326,7 +294,7 @@ def hier_update_signed_pallas(
     sq: jax.Array,       # uint32[w, C] sign multipliers
     sr: jax.Array,       # uint32[w, m] sign offsets
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Signed cascade fold into every level's table in ONE pallas_call.
 
@@ -334,53 +302,7 @@ def hier_update_signed_pallas(
     cumulative parities -- bit-exact vs core.countsketch.hier_update on
     int32 tables (|f| < 2^24, negatives allowed).  Same donation contract
     as :func:`hier_update_pallas`."""
-    w, cols = table.shape
-    if cols != hplan.padded_cols:
-        raise ValueError(
-            f"concatenated table has {cols} columns, plan expects "
-            f"{hplan.padded_cols}")
-    tile_h = hplan.tile_h
-    b, c = chunks.shape
-    grid = (w, hplan.n_tiles)
-    meta = jnp.asarray(_tile_meta_signed(hplan))
-
-    chunk_spec = pl.BlockSpec((b, c), lambda k, t: (0, 0))
-    f_spec = pl.BlockSpec((b,), lambda k, t: (0,))
-    q_spec = pl.BlockSpec((1, c), lambda k, t: (k, 0))
-    r_spec = pl.BlockSpec((1, r.shape[1]), lambda k, t: (k, 0))
-    meta_spec = pl.BlockSpec((1, 3), lambda k, t: (t, 0))
-    tbl_spec = pl.BlockSpec((1, tile_h), lambda k, t: (k, t))
-    scratch = [pltpu.VMEM((b,), jnp.int32), pltpu.VMEM((b,), jnp.int32)]
-
-    if jnp.issubdtype(table.dtype, jnp.integer):
-        fi = freqs.astype(jnp.int32)
-        flo = (fi & _LIMB_MASK).astype(jnp.float32)
-        fhi = (fi >> _LIMB_BITS).astype(jnp.float32)   # arithmetic shift
-        kernel = functools.partial(_hier_kernel_signed_int, hplan, tile_h)
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[chunk_spec, f_spec, f_spec, q_spec, r_spec,
-                      q_spec, r_spec, meta_spec, tbl_spec],
-            out_specs=tbl_spec,
-            out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-            scratch_shapes=scratch,
-            input_output_aliases={8: 0},
-            interpret=interpret,
-        )(chunks, flo, fhi, q, r, sq, sr, meta, table)
-    else:
-        kernel = functools.partial(_hier_kernel_signed_f32, hplan, tile_h)
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[chunk_spec, f_spec, q_spec, r_spec,
-                      q_spec, r_spec, meta_spec, tbl_spec],
-            out_specs=tbl_spec,
-            out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-            scratch_shapes=scratch,
-            input_output_aliases={7: 0},
-            interpret=interpret,
-        )(chunks, freqs.astype(table.dtype), q, r, sq, sr, meta, table)
+    return fold(hplan, table, chunks, freqs, (q, r, sq, sr), interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("hplan",))
@@ -397,8 +319,7 @@ def hier_update_signed_ref(
     """jnp oracle for the signed fused fold over the same concatenated
     padded table: hash indices + sign bits once per row, cascade divisions,
     per-level signed scatter-adds."""
-    idx_fine = jnp.stack([row_indices(hplan.plan, chunks, q[k], r[k])
-                          for k in range(hplan.plan.width)], axis=0)
+    idx_fine = all_indices(hplan.plan, chunks, q, r)            # int32[w, B]
     bits = jnp.stack([row_sign_bits(hplan.plan, chunks, sq[k], sr[k])
                       for k in range(hplan.plan.width)], axis=0)  # [w, B]
     w = idx_fine.shape[0]
@@ -426,9 +347,7 @@ def hier_update_ref(
     """jnp oracle over the SAME concatenated padded table: per-row composite
     hash once, cascade divisions, per-level scatter-adds (bit-identical to
     per-level core.sketch.update under the shared params)."""
-    rows = [row_indices(hplan.plan, chunks, q[k], r[k])
-            for k in range(hplan.plan.width)]
-    idx_fine = jnp.stack(rows, axis=0)                        # int32[w, B]
+    idx_fine = all_indices(hplan.plan, chunks, q, r)            # int32[w, B]
     w = idx_fine.shape[0]
     out = table
     for off, div in zip(hplan.level_offsets, hplan.level_divs):

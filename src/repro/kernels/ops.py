@@ -2,10 +2,12 @@
 
 These adapt the high-level ``SketchSpec``/``SketchState`` API (core/sketch.py)
 to the kernels: chunk extraction, padding the table width to the tile size,
-padding stream blocks to a fixed block length (so one compiled kernel serves
-the whole stream), and CPU fallback via ``interpret=True`` (the kernel body
-executes in Python on CPU -- bit-identical logic, which is how the kernels
-are validated in this container; on TPU set ``interpret=False``).
+and padding stream blocks to a fixed block length (so one compiled kernel
+serves the whole stream).  Whether a kernel runs compiled or in Pallas
+interpret mode is decided in ONE place, :func:`default_interpret`: compiled
+on a TPU, interpreted (the kernel body executed by the CPU backend --
+bit-identical logic, which is how the kernel tests run) anywhere else.  A
+caller may force either with ``interpret=True/False``.
 
 Three update modes share the wrapper:
 
@@ -17,9 +19,9 @@ Three update modes share the wrapper:
     (kernels/sketch_update_conservative.py) -- strictly tighter estimates,
     but the table is NOT linear in the stream, so ``merge``/``state()``
     (the cell-wise merge surfaces) are refused; query-side use is
-    unchanged.  When the table working set exceeds the VMEM budget the
-    update transparently takes the jnp reference path
-    (core.sketch.update_conservative), block by block.
+    unchanged.  The kernel runs in interpret mode only: on a TPU (or when
+    the table working set exceeds its VMEM budget) the update raises and
+    names the jnp path (core.sketch.update_conservative_jit).
   * ``mode="signed"``: the Count-Sketch variant (core/countsketch.py) --
     the same one-hot limb matmul with the per-group composite +-1 sign
     folded into the frequency limbs, a median-of-rows estimator on the
@@ -63,7 +65,15 @@ MODES = ("linear", "conservative", "signed")
 
 
 def default_interpret() -> bool:
+    """The one backend test for Pallas interpret mode: compiled on a TPU,
+    interpreted everywhere else."""
     return jax.default_backend() != "tpu"
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``interpret`` as passed, or :func:`default_interpret` when None.
+    Every kernel entry point resolves its ``interpret=None`` default here."""
+    return default_interpret() if interpret is None else bool(interpret)
 
 
 def check_linear_kernel_freqs(freqs: np.ndarray, table_dtype) -> None:
@@ -124,7 +134,12 @@ class KernelSketch:
         self.block_b = int(block_b)
         self.h_pad = padded_table_size(spec.table_size, tile_h)
         self.table = jnp.zeros((spec.width, self.h_pad), dtype=dtype)
-        self.interpret = default_interpret() if interpret is None else interpret
+        self.interpret = resolve_interpret(interpret)
+        if mode == "conservative" and not self.interpret:
+            raise ValueError(
+                "the conservative Pallas kernel runs in interpret mode only "
+                "(its per-item dynamic table access does not lower for TPU): "
+                "use the jnp path (core.sketch.update_conservative_jit)")
         self.mode = mode
         self._sharded_folds: dict = {}  # (mesh, data_axes) -> jitted fold
 
@@ -187,20 +202,16 @@ class KernelSketch:
         chunk_b = conservative_chunk_b(
             chunks.shape[0], chunks.shape[1], w, h_pad,
             self.table.dtype.itemsize)
-        if chunk_b is not None:
-            self.table = sketch_update_conservative_pallas(
-                self.plan, self.table, chunks, blk_f,
-                self.params.q, self.params.r,
-                chunk_b=chunk_b, interpret=self.interpret,
-            )
-        else:
-            # table working set exceeds VMEM: jnp reference path, same math
-            h = self.spec.table_size
-            state = sk.SketchState(params=self.params,
-                                   table=self.table[:, :h])
-            state = sk.update_conservative_jit(
-                self.spec, state, jnp.asarray(blk_i), blk_f)
-            self.table = self.table.at[:, :h].set(state.table)
+        if chunk_b is None:
+            raise ValueError(
+                f"conservative table working set 2*{w}*{h_pad}*"
+                f"{self.table.dtype.itemsize}B exceeds the kernel's VMEM "
+                "budget: use the jnp path (core.sketch.update_conservative_jit)")
+        self.table = sketch_update_conservative_pallas(
+            self.plan, self.table, chunks, blk_f,
+            self.params.q, self.params.r,
+            chunk_b=chunk_b, interpret=self.interpret,
+        )
 
     def query(self, items) -> np.ndarray:
         """Point estimates: min over rows (linear/conservative) or the
@@ -212,7 +223,7 @@ class KernelSketch:
         chunks = self.spec.schema.module_chunks(jnp.asarray(items))
         est = sketch_query_pallas(
             self.plan, self.table, chunks, self.params.q, self.params.r,
-            tile_h=self.tile_h, interpret=self.interpret,
+            interpret=self.interpret,
         )
         return np.asarray(est)
 
@@ -229,7 +240,7 @@ class KernelSketch:
             rows = sketch_query_signed_pallas(
                 self.plan, self.table, chunks, self.params.q, self.params.r,
                 self.cs_params.sign_q, self.cs_params.sign_r,
-                tile_h=self.tile_h, interpret=self.interpret,
+                interpret=self.interpret,
             )
             return np.asarray(rows)
         rows, _ = cskt.query_rows(self.spec, self.cs_state(),
@@ -242,10 +253,9 @@ class KernelSketch:
         conservative table is not linear in the stream, so sharded folds of
         it cannot be psum-merged (core.distributed.require_linear).
 
-        Inside shard_map each device runs the jnp reference fold (the
-        Pallas one-hot kernel is a per-device drop-in on TPU; off-TPU the
-        interpret path inside a shard_map would be pure overhead), which is
-        bit-identical to the kernel by the parity tests.  The jitted fold
+        Inside shard_map each device runs the jnp reference fold, not the
+        Pallas kernel; it is bit-identical to the kernel by the parity
+        tests.  The jitted fold
         is cached per (mesh, data_axes) and the per-shard row count padded
         to the next power of two: an eager shard_map re-traces on every
         call, which would dominate streaming ingest (same fix as
@@ -440,7 +450,7 @@ class KernelHierarchy:
         self.block_b = int(block_b)
         self.table = jnp.zeros((hspec.base.width, self.hplan.padded_cols),
                                dtype=dtype)
-        self.interpret = default_interpret() if interpret is None else interpret
+        self.interpret = resolve_interpret(interpret)
         self._state_cache: Optional[object] = None
 
     @classmethod
@@ -458,7 +468,7 @@ class KernelHierarchy:
         self.cs_params = None
         self.params = state.states[-1].params
         self.block_b = int(block_b)
-        self.interpret = default_interpret() if interpret is None else interpret
+        self.interpret = resolve_interpret(interpret)
         self._state_cache = None
         self.load_state(state)
         return self

@@ -6,14 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.hashes import IndexPlan, row_indices
-
-
-def _all_indices(plan: IndexPlan, chunks: jax.Array, q: jax.Array,
-                 r: jax.Array) -> jax.Array:
-    """int32[w, B] composite indices, one row per hash-function set."""
-    rows = [row_indices(plan, chunks, q[k], r[k]) for k in range(plan.width)]
-    return jnp.stack(rows, axis=0)
+from repro.kernels.hashes import IndexPlan, all_indices
 
 
 @functools.partial(jax.jit, static_argnames=("plan",))
@@ -27,7 +20,7 @@ def sketch_update_ref(
 ) -> jax.Array:
     """Scatter-add oracle over the (padded) table."""
     w, h_pad = table.shape
-    idx = _all_indices(plan, chunks, q, r)                        # [w, B]
+    idx = all_indices(plan, chunks, q, r)                        # [w, B]
     flat = (jnp.arange(w, dtype=jnp.int32)[:, None] * h_pad + idx).reshape(-1)
     f = jnp.broadcast_to(freqs.astype(table.dtype), (w, freqs.shape[0])).reshape(-1)
     return table.reshape(-1).at[flat].add(f).reshape(w, h_pad)
@@ -42,6 +35,6 @@ def sketch_query_ref(
     r: jax.Array,
 ) -> jax.Array:
     """Gather + min oracle: int32[Q]."""
-    idx = _all_indices(plan, chunks, q, r)                        # [w, Q]
+    idx = all_indices(plan, chunks, q, r)                        # [w, Q]
     vals = jnp.take_along_axis(table.astype(jnp.int32), idx, axis=1)
     return jnp.min(vals, axis=0)

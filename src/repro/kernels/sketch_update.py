@@ -1,116 +1,28 @@
-"""Pallas TPU kernel: sketch update as one-hot x frequency MXU matmuls.
+"""Pallas TPU kernel: flat sketch update as one-hot x frequency MXU matmuls.
 
-TPU adaptation of the paper's scalar update loop (DESIGN.md S4): scatter-add
-is the canonical TPU anti-pattern, so a stream block of B items becomes, per
-(row k, range tile t), a dense one-hot matrix ``onehot[b, j] = (idx_b ==
-tile_start + j)`` contracted with the frequency vector on the MXU:
+TPU adaptation of the paper's scalar update loop: scatter-add is the
+canonical TPU anti-pattern, so a stream block of B items becomes, per range
+tile, a dense one-hot matrix ``onehot[j, b] = (idx_b == tile_start + j)``
+contracted with the frequency operand on the MXU:
 
-    table[k, tile] += f^T . onehot          # collisions sum inside the MXU
+    table[:, tile] += f . onehot^T        # collisions sum inside the MXU
 
-Grid = (w, h/TILE_H).  Per-step VMEM: the (B, TILE_H) one-hot + the (1,
-TILE_H) table tile + the (B, C) chunk block -- e.g. B=1024, TILE_H=512 is
-~2.2 MB, comfortably inside ~16 MB VMEM, with TILE_H a multiple of the
-128-lane width.  Hash evaluation (uint32 limb CW, core/hashing.py) runs on
-the VPU inside the kernel; it is recomputed per tile, which is deliberate --
-it is cheap VPU work that overlaps the MXU contraction and avoids an HBM
-round-trip for a (w, B) index tensor.
-
-Exactness for integer tables: frequencies are split into two 12-bit limbs so
-every f32 matmul accumulates sums < 2^23 (exactly representable); limbs are
-recombined in int32.  Valid for per-arrival f < 2^24 (wrapper-checked);
-larger weights take the jnp reference path.
+The flat ``[w, h_pad]`` table is the one-level case of the fused hierarchy
+fold, so both wrappers here launch kernels/hier_update.fold on a one-level
+plan (grid ``(h_pad / tile_h,)``, hashes evaluated once per block into a
+VMEM scratch).  Exactness for integer tables comes from the bf16-exact
+frequency limbs described there: valid for per-arrival |f| < 2^24
+(wrapper-checked); larger weights take the jnp reference path.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.kernels.hashes import (
-    IndexPlan,
-    row_indices,
-    row_sign_bits,
-    signs_from_bits,
-)
-
-_LIMB_BITS = 12
-_LIMB_MASK = (1 << _LIMB_BITS) - 1
-
-
-def _update_kernel_int(plan: IndexPlan, tile_h: int,
-                       chunks_ref, flo_ref, fhi_ref, q_ref, r_ref,
-                       table_in_ref, table_out_ref):
-    """One (row, tile) step: int32 table, two 12-bit frequency limbs."""
-    t = pl.program_id(1)
-    idx = row_indices(plan, chunks_ref[...], q_ref[0], r_ref[0])      # int32[B]
-    local = idx - t * tile_h
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], tile_h), 1)
-    onehot = (local[:, None] == lanes).astype(jnp.float32)            # [B, TH]
-    dot_lo = jnp.dot(flo_ref[...][None, :], onehot,
-                     preferred_element_type=jnp.float32)              # [1, TH]
-    dot_hi = jnp.dot(fhi_ref[...][None, :], onehot,
-                     preferred_element_type=jnp.float32)
-    delta = dot_lo.astype(jnp.int32) + (dot_hi.astype(jnp.int32) << _LIMB_BITS)
-    table_out_ref[...] = table_in_ref[...] + delta
-
-
-def _update_kernel_f32(plan: IndexPlan, tile_h: int,
-                       chunks_ref, f_ref, q_ref, r_ref,
-                       table_in_ref, table_out_ref):
-    """float32-table variant (gradient sketches): single MXU contraction."""
-    t = pl.program_id(1)
-    idx = row_indices(plan, chunks_ref[...], q_ref[0], r_ref[0])
-    local = idx - t * tile_h
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], tile_h), 1)
-    onehot = (local[:, None] == lanes).astype(jnp.float32)
-    delta = jnp.dot(f_ref[...][None, :], onehot,
-                    preferred_element_type=jnp.float32)
-    table_out_ref[...] = table_in_ref[...] + delta[0][None, :]
-
-
-def _update_kernel_signed_int(plan: IndexPlan, tile_h: int,
-                              chunks_ref, flo_ref, fhi_ref, q_ref, r_ref,
-                              sq_ref, sr_ref, table_in_ref, table_out_ref):
-    """Signed mode, int32 table: the +-1 sign multiplies both frequency
-    limbs before the contraction.  Limbs come from the arithmetic split
-    f = (f & 0xFFF) + ((f >> 12) << 12), so negative values decompose
-    exactly; per-limb partial sums stay < 2^23 in magnitude (|s*limb| <=
-    4095, B <= 1024 checked by the wrapper path's callers), hence exact in
-    f32, and the int32 recombination wraps identically to the jnp
-    scatter-add reference."""
-    t = pl.program_id(1)
-    idx = row_indices(plan, chunks_ref[...], q_ref[0], r_ref[0])      # int32[B]
-    bits = row_sign_bits(plan, chunks_ref[...], sq_ref[0], sr_ref[0])
-    s = signs_from_bits(bits, len(plan.group_cols) - 1)               # f32[B]
-    local = idx - t * tile_h
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], tile_h), 1)
-    onehot = (local[:, None] == lanes).astype(jnp.float32)            # [B, TH]
-    dot_lo = jnp.dot((s * flo_ref[...])[None, :], onehot,
-                     preferred_element_type=jnp.float32)              # [1, TH]
-    dot_hi = jnp.dot((s * fhi_ref[...])[None, :], onehot,
-                     preferred_element_type=jnp.float32)
-    delta = dot_lo.astype(jnp.int32) + (dot_hi.astype(jnp.int32) << _LIMB_BITS)
-    table_out_ref[...] = table_in_ref[...] + delta
-
-
-def _update_kernel_signed_f32(plan: IndexPlan, tile_h: int,
-                              chunks_ref, f_ref, q_ref, r_ref,
-                              sq_ref, sr_ref, table_in_ref, table_out_ref):
-    """Signed mode, float32 table (gradient sketches): one contraction of
-    the sign-flipped values."""
-    t = pl.program_id(1)
-    idx = row_indices(plan, chunks_ref[...], q_ref[0], r_ref[0])
-    bits = row_sign_bits(plan, chunks_ref[...], sq_ref[0], sr_ref[0])
-    s = signs_from_bits(bits, len(plan.group_cols) - 1)
-    local = idx - t * tile_h
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], tile_h), 1)
-    onehot = (local[:, None] == lanes).astype(jnp.float32)
-    delta = jnp.dot((s * f_ref[...])[None, :], onehot,
-                    preferred_element_type=jnp.float32)
-    table_out_ref[...] = table_in_ref[...] + delta[0][None, :]
+from repro.kernels.hashes import IndexPlan
+from repro.kernels.hier_update import flat_plan, fold
 
 
 def padded_table_size(h: int, tile_h: int) -> int:
@@ -130,7 +42,7 @@ def sketch_update_pallas(
     r: jax.Array,        # uint32[w, m]
     *,
     tile_h: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fold one stream block into the (padded) table. Returns the new table.
 
@@ -138,43 +50,8 @@ def sketch_update_pallas(
     ingest accumulates in place instead of copying the table every call.
     Callers must rebind to the returned table (KernelSketch.update does).
     """
-    w, h_pad = table.shape
-    if h_pad % tile_h:
-        raise ValueError(f"padded table width {h_pad} not a multiple of {tile_h}")
-    n_tiles = h_pad // tile_h
-    b, c = chunks.shape
-    grid = (w, n_tiles)
-
-    chunk_spec = pl.BlockSpec((b, c), lambda k, t: (0, 0))
-    f_spec = pl.BlockSpec((b,), lambda k, t: (0,))
-    q_spec = pl.BlockSpec((1, c), lambda k, t: (k, 0))
-    r_spec = pl.BlockSpec((1, r.shape[1]), lambda k, t: (k, 0))
-    tbl_spec = pl.BlockSpec((1, tile_h), lambda k, t: (k, t))
-
-    if jnp.issubdtype(table.dtype, jnp.integer):
-        flo = (freqs.astype(jnp.int32) & _LIMB_MASK).astype(jnp.float32)
-        fhi = (freqs.astype(jnp.int32) >> _LIMB_BITS).astype(jnp.float32)
-        kernel = functools.partial(_update_kernel_int, plan, tile_h)
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[chunk_spec, f_spec, f_spec, q_spec, r_spec, tbl_spec],
-            out_specs=tbl_spec,
-            out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-            input_output_aliases={5: 0},
-            interpret=interpret,
-        )(chunks, flo, fhi, q, r, table)
-    else:
-        kernel = functools.partial(_update_kernel_f32, plan, tile_h)
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[chunk_spec, f_spec, q_spec, r_spec, tbl_spec],
-            out_specs=tbl_spec,
-            out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-            input_output_aliases={4: 0},
-            interpret=interpret,
-        )(chunks, freqs.astype(table.dtype), q, r, table)
+    return fold(flat_plan(plan, table.shape[1], tile_h), table, chunks,
+                freqs, (q, r), interpret)
 
 
 @functools.partial(
@@ -192,50 +69,12 @@ def sketch_update_signed_pallas(
     sr: jax.Array,       # uint32[w, m]   sign-hash offsets
     *,
     tile_h: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Signed (Count-Sketch) fold: cell += sign(row, item) * f.
 
     Same donation contract as :func:`sketch_update_pallas`; freqs may be
     negative (turnstile).  Bit-exact vs core.countsketch.update on int32
     tables for |f| < 2^24."""
-    w, h_pad = table.shape
-    if h_pad % tile_h:
-        raise ValueError(f"padded table width {h_pad} not a multiple of {tile_h}")
-    n_tiles = h_pad // tile_h
-    b, c = chunks.shape
-    grid = (w, n_tiles)
-
-    chunk_spec = pl.BlockSpec((b, c), lambda k, t: (0, 0))
-    f_spec = pl.BlockSpec((b,), lambda k, t: (0,))
-    q_spec = pl.BlockSpec((1, c), lambda k, t: (k, 0))
-    r_spec = pl.BlockSpec((1, r.shape[1]), lambda k, t: (k, 0))
-    tbl_spec = pl.BlockSpec((1, tile_h), lambda k, t: (k, t))
-
-    if jnp.issubdtype(table.dtype, jnp.integer):
-        fi = freqs.astype(jnp.int32)
-        flo = (fi & _LIMB_MASK).astype(jnp.float32)
-        fhi = (fi >> _LIMB_BITS).astype(jnp.float32)   # arithmetic shift
-        kernel = functools.partial(_update_kernel_signed_int, plan, tile_h)
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[chunk_spec, f_spec, f_spec, q_spec, r_spec,
-                      q_spec, r_spec, tbl_spec],
-            out_specs=tbl_spec,
-            out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-            input_output_aliases={7: 0},
-            interpret=interpret,
-        )(chunks, flo, fhi, q, r, sq, sr, table)
-    else:
-        kernel = functools.partial(_update_kernel_signed_f32, plan, tile_h)
-        return pl.pallas_call(
-            kernel,
-            grid=grid,
-            in_specs=[chunk_spec, f_spec, q_spec, r_spec,
-                      q_spec, r_spec, tbl_spec],
-            out_specs=tbl_spec,
-            out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-            input_output_aliases={6: 0},
-            interpret=interpret,
-        )(chunks, freqs.astype(table.dtype), q, r, sq, sr, table)
+    return fold(flat_plan(plan, table.shape[1], tile_h), table, chunks,
+                freqs, (q, r, sq, sr), interpret)

@@ -40,7 +40,13 @@ table in + out blocks) plus the double-buffered chunk inputs.
 :func:`conservative_chunk_b` picks the largest power-of-two B-chunk that
 fits beside the table -- the chunked-B variant -- and returns None when the
 table itself cannot fit, in which case the caller must take the jnp
-reference path (``kernels/ops.KernelSketch`` does this automatically).
+reference path (``kernels/ops.KernelSketch`` refuses with an error naming
+it).
+
+TPU lowering: the per-item ``idx[k, i]`` read and the dynamic-lane table
+access in the ``fori_loop`` body have no Mosaic lowering, so this kernel
+runs in interpret mode only; a compiled launch is refused up front with an
+error naming the jnp path (core.sketch.update_conservative_jit).
 """
 from __future__ import annotations
 
@@ -121,7 +127,7 @@ def sketch_update_conservative_pallas(
     *,
     chunk_b: Optional[int] = None,
     vmem_limit_bytes: int = _VMEM_BUDGET_BYTES,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Conservatively fold one stream block into the (padded) table.
 
@@ -131,6 +137,12 @@ def sketch_update_conservative_pallas(
     :func:`conservative_chunk_b` to pre-check and route to the reference
     path instead.
     """
+    from repro.kernels.ops import resolve_interpret
+
+    if not resolve_interpret(interpret):
+        raise ValueError(
+            "the conservative Pallas kernel runs in interpret mode only: "
+            "use the jnp path (core.sketch.update_conservative_jit)")
     w, h_pad = table.shape
     b, c = chunks.shape
     if chunk_b is None:
@@ -160,5 +172,5 @@ def sketch_update_conservative_pallas(
         out_specs=tbl_spec,
         out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
         input_output_aliases={4: 0},
-        interpret=interpret,
+        interpret=True,
     )(chunks, freqs.astype(table.dtype), q, r, table)

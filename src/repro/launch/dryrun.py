@@ -425,11 +425,8 @@ def main() -> None:
                          "model cells")
     args = ap.parse_args()
 
-    try:  # persistent compilation cache speeds up resumed sweeps
-        jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 10)
-    except Exception:
-        pass
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()  # persistent cache speeds up resumed sweeps
 
     if args.sketch_cells:
         run_sketch_cells(skip_existing=not args.force)

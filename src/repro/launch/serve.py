@@ -157,6 +157,8 @@ def main() -> None:
     ap.add_argument("--topk", type=int, default=32)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.sketch_autotune:
         run_sketch_autotune(args)
     else:

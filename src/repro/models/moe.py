@@ -18,7 +18,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.layers import dense_init
 from repro.models.shard_ctx import DP, MP, constrain

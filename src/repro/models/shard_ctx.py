@@ -21,7 +21,7 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 DP = "__dp__"
 MP = "__mp__"
@@ -70,5 +70,8 @@ def constrain(x: jax.Array, *tokens) -> jax.Array:
             axes.pop()
         parts.append(tuple(axes) if len(axes) > 1 else (axes[0] if axes else None))
     parts += [None] * (x.ndim - len(parts))
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*parts)))
+    sharding = NamedSharding(mesh, P(*parts))
+    if AxisType.Explicit in mesh.axis_types:
+        # Explicit axes carry the sharding in the type: move the data
+        return jax.sharding.reshard(x, sharding)
+    return jax.lax.with_sharding_constraint(x, sharding)

@@ -83,8 +83,9 @@ class SketchTopKEndpoint(MigratingSurface):
     family).  ``use_update_kernel=True`` additionally folds each block into
     all level tables with the fused single-launch Pallas kernel
     (kernels/ops.KernelHierarchy); linear mode only -- a conservative
-    endpoint silently keeps the jnp per-level sequential folds, which
-    already share the cascade's one hash pass.
+    endpoint refuses it (its jnp per-level sequential folds already share
+    the cascade's one hash pass).  ``use_kernel=True`` answers the descent
+    through the Pallas gather kernel (kernels/hier_query.py).
 
     Linear endpoints shard naturally: run one per ingest worker and fold
     with ``merge_from`` at query time (tables cell-wise, exact by
@@ -108,6 +109,10 @@ class SketchTopKEndpoint(MigratingSurface):
 
         if mode not in ("linear", "conservative"):
             raise ValueError(f"mode must be 'linear' or 'conservative', got {mode!r}")
+        if use_update_kernel and mode != "linear":
+            raise ValueError(
+                "the fused update kernel is linear-only: a conservative "
+                "endpoint folds on the jnp path (use_update_kernel=False)")
         self._hh = hh
         self._kh = None
         self._migration = None
@@ -122,7 +127,7 @@ class SketchTopKEndpoint(MigratingSurface):
             SpaceSaving(self.max_candidates, len(g))
             for g in base_spec.partition
         ]
-        if use_update_kernel and mode == "linear":
+        if use_update_kernel:
             from repro.kernels.ops import KernelHierarchy
 
             # the endpoint's state moves into the kernel wrapper's

@@ -27,11 +27,12 @@ def _run(code: str, devices: int = 8) -> str:
 def test_sharded_sketch_build_equals_serial():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core import sketch as sk, distributed as dist
         from repro.core.hashing import KeySchema
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         schema = KeySchema(domains=(1 << 20, 1 << 20))
         spec = sk.mod_sketch_spec(schema, [(0,), (1,)], (32, 64), 4)
         params = sk.init_params(spec, jax.random.PRNGKey(0))
@@ -59,10 +60,11 @@ def test_sharded_sketch_build_equals_serial():
 def test_lazy_local_tables_merge():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import sketch as sk, distributed as dist
         from repro.core.hashing import KeySchema
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         schema = KeySchema(domains=(4096, 4096))
         spec = sk.mod_sketch_spec(schema, [(0,), (1,)], (16, 16), 3)
         params = sk.init_params(spec, jax.random.PRNGKey(1))
@@ -90,6 +92,7 @@ def test_small_mesh_dryrun_train_and_decode():
     lowering + compile + loop-aware roofline must succeed end to end."""
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         import dataclasses
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro import hlo_analysis as ha
@@ -98,7 +101,7 @@ def test_small_mesh_dryrun_train_and_decode():
         from repro.models import sharding as shd, shard_ctx, transformer as tfm
         from repro.training import train_loop as tl
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         for arch in ("gemma2-9b", "mixtral-8x22b", "mamba2-130m"):
             cfg = get_reduced(arch)
             tcfg = tl.TrainConfig()
@@ -146,6 +149,7 @@ def test_small_mesh_dryrun_train_and_decode():
 def test_moe_local_dispatch_matches_global_when_dropless():
     print(_run("""
         import dataclasses
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_reduced
         from repro.models import moe as moe_mod, shard_ctx
@@ -154,7 +158,7 @@ def test_moe_local_dispatch_matches_global_when_dropless():
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
                               jnp.float32).astype(cfg.activation_dtype)
         y_global, _ = moe_mod.apply_moe(cfg, p, x)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg_l = dataclasses.replace(cfg, moe_dispatch="local")
         with shard_ctx.activation_sharding(mesh):
             y_local, aux = jax.jit(
@@ -170,6 +174,7 @@ def test_moe_local_dispatch_matches_global_when_dropless():
 def test_moe_ep_shardmap_matches_global_when_dropless():
     print(_run("""
         import dataclasses
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_reduced
         from repro.models import moe as moe_mod, shard_ctx
@@ -178,7 +183,7 @@ def test_moe_ep_shardmap_matches_global_when_dropless():
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
                               jnp.float32).astype(cfg.activation_dtype)
         y_global, _ = moe_mod.apply_moe(cfg, p, x)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg_ep = dataclasses.replace(cfg, moe_dispatch="ep_shardmap")
         with shard_ctx.activation_sharding(mesh):
             y_ep, aux = jax.jit(
@@ -194,11 +199,12 @@ def test_moe_ep_shardmap_matches_global_when_dropless():
 def test_elastic_remesh():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.training.fault_tolerance import elastic_remesh
 
-        big = jax.make_mesh((8,), ("data",))
-        small = jax.make_mesh((4,), ("data",))
+        big = make_mesh((8,), ("data",))
+        small = make_mesh((4,), ("data",))
         x = jax.device_put(jnp.arange(64.0).reshape(8, 8),
                            NamedSharding(big, P("data", None)))
         y = elastic_remesh({"x": x}, small, lambda leaf: P("data", None))

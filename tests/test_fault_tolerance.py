@@ -28,6 +28,7 @@ import pytest
 
 from repro.core import sketch as sk
 from repro.kernels.ops import KernelSketch
+from repro.launch.mesh import make_mesh
 from repro.streams import zipf_hh_workload
 from repro.training.fault_tolerance import (
     StragglerMonitor,
@@ -153,7 +154,7 @@ def test_straggler_monitor_flags_and_recovers():
 def test_elastic_remesh_single_device_roundtrip():
     # 1->1 remesh is the degenerate leg runnable on any host: values and
     # structure survive the device_put relayout
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import PartitionSpec as P
 
     state = {"table": jax.numpy.arange(64, dtype=jax.numpy.int32).reshape(8, 8),
@@ -167,12 +168,13 @@ def test_elastic_remesh_single_device_roundtrip():
 def test_elastic_remesh_multi_device_shrink_grow():
     print(_run("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.training.fault_tolerance import elastic_remesh
 
         assert jax.device_count() >= 8, jax.device_count()
-        mesh8 = jax.make_mesh((8,), ("data",))
-        mesh4 = jax.make_mesh((4,), ("data",))   # lost half the fleet
+        mesh8 = make_mesh((8,), ("data",))
+        mesh4 = make_mesh((4,), ("data",))   # lost half the fleet
         x = jax.device_put(
             jax.numpy.arange(1024, dtype=jax.numpy.float32).reshape(8, 128),
             NamedSharding(mesh8, P("data")))

@@ -17,6 +17,7 @@ from repro.core import hierarchy as hh
 from repro.core import sketch as sk
 from repro.core.hashing import KeySchema
 from repro.kernels import KernelHierarchy, hier_update_pallas, make_hier_plan
+from repro.launch.mesh import make_mesh
 from repro.serving.engine import SketchTopKEndpoint
 from repro.streams import zipf_hh_workload
 
@@ -284,7 +285,7 @@ def test_endpoint_fused_merge_and_to_sharded_roundtrip():
     for x, y in zip(a.state.states, whole.state.states):
         np.testing.assert_array_equal(np.asarray(x.table),
                                       np.asarray(y.table))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     svc = a.to_sharded(mesh)
     hi_a, _ = svc.heavy_hitters(wl.threshold)
     hi_w, _ = whole.heavy_hitters(wl.threshold)
@@ -292,22 +293,27 @@ def test_endpoint_fused_merge_and_to_sharded_roundtrip():
 
 
 def test_conservative_endpoint_ignores_update_kernel_flag():
-    """Conservative mode cannot take the fused linear kernel; the flag
-    falls back to the jnp per-level folds (which still share the cascade's
-    single hash pass) and the endpoint behaves identically."""
+    """Conservative mode cannot take the fused linear kernel: asking for it
+    is refused at construction (never silently ignored), and the endpoint
+    without the flag folds on the jnp per-level path (which still shares
+    the cascade's single hash pass), matching the core conservative fold."""
     hspec_spec = sk.mod_sketch_spec(KeySchema(domains=(1 << 16, 1 << 16)),
                                     [(0,), (1,)], (16, 16), 2)
     rng = np.random.default_rng(0)
     items = rng.integers(0, 1 << 12, size=(200, 2)).astype(np.uint32)
     freqs = rng.integers(1, 50, size=200).astype(np.int64)
     key = jax.random.PRNGKey(1)
+    with pytest.raises(ValueError, match="use_update_kernel=False"):
+        SketchTopKEndpoint(hspec_spec, key, mode="conservative",
+                           use_update_kernel=True)
     c1 = SketchTopKEndpoint(hspec_spec, key, mode="conservative")
-    c2 = SketchTopKEndpoint(hspec_spec, key, mode="conservative",
-                            use_update_kernel=True)
-    assert c2._kh is None
+    assert c1._kh is None
     c1.ingest(items, freqs)
-    c2.ingest(items, freqs)
-    for a, b in zip(c1.state.states, c2.state.states):
+    hspec = hh.HierarchySpec.from_spec(hspec_spec)
+    want = hh.update_conservative_jit(
+        hspec, hh.init_hierarchy(hspec, key), jnp.asarray(items),
+        jnp.asarray(freqs))
+    for a, b in zip(c1.state.states, want.states):
         np.testing.assert_array_equal(np.asarray(a.table),
                                       np.asarray(b.table))
 
@@ -342,7 +348,7 @@ def test_endpoint_ingest_after_to_sharded_keeps_service_alive():
     ep = SketchTopKEndpoint(spec, jax.random.PRNGKey(0))
     half = len(wl.stream.items) // 2
     ep.ingest(wl.stream.items[:half], wl.stream.freqs[:half])
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     svc = ep.to_sharded(mesh)
     snapshot = [np.asarray(st.table).copy() for st in svc.state().states]
     # continued single-shard ingest donates the ENDPOINT's tables ...
@@ -360,7 +366,7 @@ def test_sharded_build_bit_exact_under_shared_params():
     hspec = _hier(ranges=(16, 8, 4), w=2)
     key = jax.random.PRNGKey(2)
     items, freqs = _stream(hspec, 512, seed=10)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     state0 = hh.init_hierarchy(hspec, key)
     got = hh.sharded_hierarchy_build(hspec, state0, mesh, ("data",),
                                      jnp.asarray(items),

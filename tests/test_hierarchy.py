@@ -96,7 +96,7 @@ def test_kernel_matches_reference_exactly():
     cp = rng.integers(0, 64, (w, c)).astype(np.uint32)
     pp = (rng.integers(0, h // 64, (w, p)) * 64).astype(np.uint32)
     got = hier_candidate_query(table, jnp.asarray(pp), jnp.asarray(cp),
-                               tile_h=256, interpret=True)
+                               interpret=True)
     want = hier_candidate_query_ref(table, jnp.asarray(pp), jnp.asarray(cp))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -180,9 +180,9 @@ def test_find_heavy_hitters_validates_candidates():
 
 
 def test_kernel_rejects_non_int32_tables():
-    """The Pallas two-limb gather only covers int32; other dtypes must be
-    refused loudly (the descent then takes the dtype-preserving reference
-    path -- exercised under x64 below)."""
+    """The Pallas candidate gather covers int32; other dtypes must be
+    refused loudly (the dtype-preserving reference path is exercised under
+    x64 below)."""
     from repro.kernels.hier_query import hier_candidate_query
     with pytest.raises(ValueError, match="int32 tables only"):
         hier_candidate_query(jnp.zeros((2, 64), jnp.float32),
@@ -192,9 +192,9 @@ def test_kernel_rejects_non_int32_tables():
 
 def test_int64_tables_route_to_dtype_preserving_path():
     """use_kernel on an int64 hierarchy must not wrap counts through the
-    kernel's int32 limb split: the query silently takes the reference path
-    and keeps exact 64-bit estimates.  int64 tables only exist under
-    jax_enable_x64, so this runs in a subprocess."""
+    kernel's int32 gather: the kernel path refuses and names the reference
+    path, which keeps exact 64-bit estimates.  int64 tables only exist
+    under jax_enable_x64, so this runs in a subprocess."""
     import os
     import subprocess
     import sys
@@ -218,11 +218,17 @@ def test_int64_tables_route_to_dtype_preserving_path():
                                    freqs, dtype=jnp.int64)
         assert state.states[0].table.dtype == jnp.int64
         cands = group_candidates(base, items)
-        for uk in (False, True):
-            gi, ge = hh.find_heavy_hitters(hspec, state, 1 << 33, cands,
-                                           use_kernel=uk)
-            assert gi.tolist() == [[7, 9]], (uk, gi)
-            assert int(ge[0]) >= 1 << 33, (uk, ge)
+        gi, ge = hh.find_heavy_hitters(hspec, state, 1 << 33, cands,
+                                       use_kernel=False)
+        assert gi.tolist() == [[7, 9]], gi
+        assert int(ge[0]) >= 1 << 33, ge
+        try:
+            hh.find_heavy_hitters(hspec, state, 1 << 33, cands,
+                                  use_kernel=True)
+        except ValueError as e:
+            assert "use_kernel=False" in str(e), e
+        else:
+            raise AssertionError("kernel path accepted an int64 table")
         print("int64 ok")
     """)], capture_output=True, text=True, env=env, timeout=600)
     assert out.returncode == 0, f"stderr:\n{out.stderr[-3000:]}"

@@ -45,9 +45,10 @@ def test_scan_flops_counted_with_trip_count():
 def test_collectives_inside_loops_are_scaled():
     _run("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro import hlo_analysis as ha
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         T, d = 10, 64
         w = jax.ShapeDtypeStruct((T, d, d), jnp.float32)
         x = jax.ShapeDtypeStruct((32, d), jnp.float32)

@@ -168,8 +168,9 @@ def test_conservative_chunked_b_variant_matches():
 
 
 def test_conservative_vmem_fallback_reference_path(monkeypatch):
-    """When the table working set exceeds VMEM the wrapper must route to
-    core.sketch.update_conservative, bit-for-bit."""
+    """When the table working set exceeds VMEM the wrapper must refuse and
+    name the jnp reference path -- never swap it in silently -- and the
+    named path gives the conservative table bit-for-bit."""
     import repro.kernels.ops as ops_mod
 
     spec = sk.mod_sketch_spec(_SCHEMA, [(0,), (1,)], (48, 90), 4)
@@ -180,9 +181,16 @@ def test_conservative_vmem_fallback_reference_path(monkeypatch):
                         lambda *a, **k: None)
     ks = KernelSketch(spec, jax.random.PRNGKey(7), tile_h=512, block_b=128,
                       interpret=True, mode="conservative")
-    ks.update(items, freqs)
+    with pytest.raises(ValueError, match="update_conservative_jit"):
+        ks.update(items, freqs)
+    assert ks.table_view().max() == 0
     core = _conservative_reference(spec, ks.params, items, freqs, jnp.int32)
-    np.testing.assert_array_equal(ks.table_view(), np.asarray(core.table))
+    state = sk.update_conservative_jit(
+        spec, sk.SketchState(params=ks.params, table=jnp.zeros(
+            (spec.width, spec.table_size), jnp.int32)),
+        jnp.asarray(items), jnp.asarray(freqs))
+    np.testing.assert_array_equal(np.asarray(state.table),
+                                  np.asarray(core.table))
 
 
 def test_freq_guard_rejects_negative_and_large_magnitude():

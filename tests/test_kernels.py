@@ -83,7 +83,7 @@ def test_query_kernel_matches_oracle(domains, part, ranges, w, tile_h, b):
                                   chunks, jnp.asarray(freqs), params.q,
                                   params.r)
     got = sketch_query_pallas(plan, table, chunks[:61], params.q, params.r,
-                              tile_h=tile_h, interpret=True)
+                              interpret=True)
     want = ref.sketch_query_ref(plan, table, chunks[:61], params.q, params.r)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 

@@ -37,6 +37,7 @@ import pytest
 from _propcheck import given, settings, st
 
 from repro.core import sketch as sk
+from repro.launch.mesh import make_mesh
 from repro.serving.engine import SketchTopKEndpoint
 from repro.serving.migration import SpecMigration, require_not_migrating
 from repro.streams import ngram_hh_workload, zipf_hh_workload
@@ -228,6 +229,7 @@ def test_migration_progress_monotone(seed):
 def test_sharded_migration_shard_invariant():
     print(_run("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import sketch as sk
         from repro.serving.sharded_topk import ShardedTopKService
         from repro.streams import zipf_hh_workload
@@ -247,7 +249,7 @@ def test_sharded_migration_shard_invariant():
         assert counts[-1] >= 2
         results = {}
         for c in counts:
-            mesh = jax.make_mesh((c,), ("data",))
+            mesh = make_mesh((c,), ("data",))
             svc = ShardedTopKService(spec_old, key, mesh)
             svc.ingest(items[:cut1], freqs[:cut1])
             svc.begin_migration(spec_new, mig_key, warmup=warm)
@@ -266,7 +268,7 @@ def test_sharded_migration_shard_invariant():
                 assert np.array_equal(ta, tb)
 
         # migrated == fresh service on the new spec, post-warmup stream
-        mesh = jax.make_mesh((counts[-1],), ("data",))
+        mesh = make_mesh((counts[-1],), ("data",))
         fresh = ShardedTopKService(spec_new, mig_key, mesh)
         fresh.ingest(items[cut1:cut2], freqs[cut1:cut2])
         fresh.ingest(items[cut2:], freqs[cut2:])
@@ -308,7 +310,7 @@ def test_mid_warmup_merge_and_shard_refused():
         ep.merge_from(other)
     with pytest.raises(ValueError, match="migration"):
         other.merge_from(ep)          # source side mid-warmup: also refused
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with pytest.raises(ValueError, match="migration"):
         ep.to_sharded(mesh)
     with pytest.raises(ValueError, match="already in flight"):

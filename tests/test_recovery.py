@@ -553,6 +553,7 @@ def test_checkpoint_mid_warmup_refuses_with_clear_error():
 def test_sharded_remesh_grow_shrink_bitwise():
     print(_run("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import sketch as sk
         from repro.serving.sharded_topk import ShardedTopKService
         from repro.streams import zipf_hh_workload
@@ -565,8 +566,8 @@ def test_sharded_remesh_grow_shrink_bitwise():
                   for s in range(0, len(items), 500)]
         half = len(blocks) // 2
         assert jax.device_count() >= 4, jax.device_count()
-        mesh2 = jax.make_mesh((2,), ("data",))
-        mesh4 = jax.make_mesh((4,), ("data",))
+        mesh2 = make_mesh((2,), ("data",))
+        mesh4 = make_mesh((4,), ("data",))
 
         ref = ShardedTopKService(spec, key, mesh2, sync_every=2)
         for it, fr in blocks: ref.ingest(it, fr)
@@ -590,6 +591,7 @@ def test_sharded_remesh_grow_shrink_bitwise():
 def test_sharded_snapshot_restores_across_shard_counts():
     print(_run("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import sketch as sk
         from repro.serving.sharded_topk import ShardedTopKService
         from repro.streams import zipf_hh_workload
@@ -601,8 +603,8 @@ def test_sharded_snapshot_restores_across_shard_counts():
         blocks = [(items[s:s+500], freqs[s:s+500])
                   for s in range(0, len(items), 500)]
         half = len(blocks) // 2
-        mesh2 = jax.make_mesh((2,), ("data",))
-        mesh4 = jax.make_mesh((4,), ("data",))
+        mesh2 = make_mesh((2,), ("data",))
+        mesh4 = make_mesh((4,), ("data",))
 
         ref = ShardedTopKService(spec, key, mesh2, sync_every=2)
         for it, fr in blocks: ref.ingest(it, fr)
@@ -626,6 +628,7 @@ def test_sharded_snapshot_restores_across_shard_counts():
 def test_sharded_kill_recover_bitwise():
     print(_run("""
         import tempfile, jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import sketch as sk
         from repro.serving.sharded_topk import ShardedTopKService
         from repro.serving.faults import ServingSupervisor, FaultPlan
@@ -637,7 +640,7 @@ def test_sharded_kill_recover_bitwise():
         items, freqs = wl.stream.items, wl.stream.freqs
         ops = [("block", items[s:s+500], freqs[s:s+500])
                for s in range(0, len(items), 500)]
-        mesh = jax.make_mesh((min(4, jax.device_count()),), ("data",))
+        mesh = make_mesh((min(4, jax.device_count()),), ("data",))
 
         def factory():
             return ShardedTopKService(spec, key, mesh, sync_every=2)

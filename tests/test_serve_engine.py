@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.core import hierarchy as hh
 from repro.core import sketch as sk
+from repro.launch.mesh import make_mesh
 from repro.serving.protocol import ServeEngineProtocol
 from repro.serving.sketch_engine import (
     SketchQuery,
@@ -126,7 +127,7 @@ def test_engine_staleness0_parity_with_sharded_service():
 
     stream = _stream()
     spec = _spec(stream)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     ref = ShardedTopKService(spec, KEY, mesh, sync_every=1)
     svc = ShardedTopKService(spec, KEY, mesh, sync_every=None)
     eng = SketchServeEngine(svc, max_staleness=0, shard_sync_every=3)

@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 from _propcheck import given, settings, st
+from repro.launch.mesh import make_mesh
 
 _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 _DEVICES = int(os.environ.get("REPRO_TEST_DEVICES", "8"))
@@ -42,6 +43,7 @@ def _run(code: str, devices: int = _DEVICES) -> str:
 def test_shard_invariance_tables_and_topk():
     print(_run(f"""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import sketch as sk, hierarchy as hh
         from repro.serving.sharded_topk import ShardedTopKService
         from repro.streams import zipf_hh_workload
@@ -56,7 +58,7 @@ def test_shard_invariance_tables_and_topk():
 
         ref = None
         for ci, c in enumerate(counts):
-            mesh = jax.make_mesh((c,), ("data",))
+            mesh = make_mesh((c,), ("data",))
             svc = ShardedTopKService(spec, key, mesh, sync_every=2)
             # a different split of the same stream for every shard count
             edges = np.linspace(0, len(items), ci + 3).astype(int)
@@ -78,7 +80,7 @@ def test_shard_invariance_tables_and_topk():
                 assert np.array_equal(ref[4], tk_e)
 
         # same shard count, two different splits: also identical
-        mesh = jax.make_mesh((counts[-1],), ("data",))
+        mesh = make_mesh((counts[-1],), ("data",))
         svc2 = ShardedTopKService(spec, key, mesh, sync_every=1)
         svc2.ingest(items[:100], freqs[:100])
         svc2.ingest(items[100:], freqs[100:])
@@ -107,6 +109,7 @@ def test_sharded_service_sync_cadence_and_kernel_descent():
     agree with the reference descent on the merged tables."""
     print(_run("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import sketch as sk
         from repro.serving.sharded_topk import ShardedTopKService
         from repro.streams import zipf_hh_workload
@@ -116,7 +119,7 @@ def test_sharded_service_sync_cadence_and_kernel_descent():
         key = jax.random.PRNGKey(1)
         items, freqs = wl.stream.items, wl.stream.freqs
         c = min(4, jax.device_count())
-        mesh = jax.make_mesh((c,), ("data",))
+        mesh = make_mesh((c,), ("data",))
 
         lazy = ShardedTopKService(spec, key, mesh, sync_every=None)
         sync = ShardedTopKService(spec, key, mesh, sync_every=1)
@@ -137,11 +140,60 @@ def test_sharded_service_sync_cadence_and_kernel_descent():
     """))
 
 
+def test_kernel_descent_runs_on_one_replica():
+    """The merged tables are replicated over the mesh, and XLA cannot
+    partition a Pallas kernel: the kernel descent must get every operand
+    on one device (the first replica), before and after a remesh, and
+    answer like the reference descent."""
+    print(_run("""
+        import jax, numpy as np
+        from repro.launch.mesh import make_mesh
+        from repro.core import hierarchy as hh, sketch as sk
+        from repro.kernels import hier_query
+        from repro.serving.sharded_topk import ShardedTopKService
+        from repro.streams import zipf_hh_workload
+
+        launched = []
+        kernel = hier_query.hier_candidate_query_batched
+
+        def one_device_only(table, pp, cp, **kw):
+            for a in (table, pp, cp):
+                assert len(a.sharding.device_set) == 1, a.sharding
+            launched.append(table.shape)
+            return kernel(table, pp, cp, **kw)
+        hier_query.hier_candidate_query_batched = one_device_only
+
+        wl = zipf_hh_workload(n_occurrences=20_000, n_edges=3_000, seed=5)
+        spec = sk.mod_sketch_spec(wl.stream.schema, [(0,), (1,)], (64, 64), 3)
+        key = jax.random.PRNGKey(2)
+        items, freqs = wl.stream.items, wl.stream.freqs
+        ref = ShardedTopKService(spec, key, make_mesh((1,), ("data",)))
+        ref.ingest(items, freqs)
+        want = ref.heavy_hitters(wl.threshold)
+
+        svc = ShardedTopKService(spec, key, make_mesh((4,), ("data",)),
+                                 use_kernel=True)
+        svc.ingest(items, freqs)
+        assert len(svc.state().states[-1].table.sharding.device_set) == 4
+        for mesh in (None, make_mesh((2,), ("data",))):
+            if mesh is not None:
+                svc.remesh(mesh)     # params now replicated on the mesh too
+            got = hh.batched_find_heavy_hitters(
+                svc.hspec, svc.state(), [wl.threshold], svc.candidates(),
+                use_kernel=True)[0]
+            assert np.array_equal(got[0], want[0])
+            assert np.array_equal(got[1], want[1])
+        assert launched
+        print("kernel descent on one replica OK")
+    """, devices=4))
+
+
 def test_endpoint_to_sharded_continuation():
     """Promoting a single-shard endpoint carries tables/pools/total over,
     and continued sharded ingest matches one endpoint fed the full stream."""
     print(_run("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import sketch as sk
         from repro.serving.engine import SketchTopKEndpoint
         from repro.streams import zipf_hh_workload
@@ -153,7 +205,7 @@ def test_endpoint_to_sharded_continuation():
 
         ep = SketchTopKEndpoint(spec, key)
         ep.ingest(wl.stream.items[:half], wl.stream.freqs[:half])
-        mesh = jax.make_mesh((min(4, jax.device_count()),), ("data",))
+        mesh = make_mesh((min(4, jax.device_count()),), ("data",))
         svc = ep.to_sharded(mesh)
         svc.ingest(wl.stream.items[half:], wl.stream.freqs[half:])
 
@@ -261,10 +313,11 @@ def test_sharded_hierarchy_build_equals_single_device():
     vs build_hierarchy, across a few spec shapes (subprocess sweep)."""
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core import hierarchy as hh, sketch as sk
         from repro.core.hashing import KeySchema
 
-        mesh = jax.make_mesh((jax.device_count(),), ("data",))
+        mesh = make_mesh((jax.device_count(),), ("data",))
         rng = np.random.default_rng(0)
         for ranges, w, n_items in (((16, 16), 3, 4096), ((32, 8), 2, 2048)):
             schema = KeySchema(domains=(1 << 20, 1 << 20))
@@ -302,7 +355,7 @@ def test_conservative_refuses_every_sharded_entry_point():
     spec = sk.mod_sketch_spec(schema, [(0,), (1,)], (8, 8), 2)
     hspec = hh.HierarchySpec.from_spec(spec)
     key = jax.random.PRNGKey(0)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
 
     with pytest.raises(ValueError, match="single-shard"):
         ShardedTopKService(spec, key, mesh, mode="conservative")
@@ -341,7 +394,7 @@ def test_kernel_sketch_sharded_update_parity():
     items = rng.integers(0, 1 << 20, size=(700, 2),
                          dtype=np.int64).astype(np.uint32)
     freqs = rng.integers(1, 9, size=700).astype(np.int32)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
 
     ks = KernelSketch(spec, key)
     for s, e in ((0, 300), (300, 700)):   # uneven blocks share one compile

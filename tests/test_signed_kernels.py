@@ -89,7 +89,7 @@ def test_flat_signed_query_kernel_bit_exact():
     chunks = jnp.asarray(spec.schema.module_chunks_np(q_items))
     rows = sketch_query_signed_pallas(
         plan, table, chunks, params.base.q, params.base.r, params.sign_q,
-        params.sign_r, tile_h=128, interpret=True)
+        params.sign_r, interpret=True)
     ref_rows, ref_med = cs.query_rows(spec, st, jnp.asarray(q_items))
     np.testing.assert_array_equal(np.asarray(rows),
                                   np.asarray(ref_rows).astype(np.int32))
@@ -151,7 +151,7 @@ def test_signed_candidate_grid_kernel_bit_exact():
     pp, cp, sp, sc = cs.candidate_signed_partials(
         hspec, params, 1, jnp.asarray(prefixes), jnp.asarray(values))
     ker = hier_candidate_query_signed(hier.tables[1], pp, cp, sp, sc,
-                                      tile_h=128, interpret=True)
+                                      interpret=True)
     ref = hier_candidate_query_signed_ref(hier.tables[1], pp, cp, sp, sc)
     np.testing.assert_array_equal(np.asarray(ker).astype(np.float32),
                                   np.asarray(ref))
@@ -178,7 +178,7 @@ def test_candidate_estimates_kernel_matches_ref_with_chunking():
     prefixes = np.unique(items[:, :1], axis=0)[:17]  # odd: forces pad chunk
     values = np.unique(items[:, 1:], axis=0)[:8]
     a = cs.candidate_estimates(hspec, hier, 1, prefixes, values,
-                               use_kernel=True, interpret=True, tile_h=128,
+                               use_kernel=True, interpret=True,
                                max_batch=40)
     b = cs.candidate_estimates(hspec, hier, 1, prefixes, values,
                                use_kernel=False)
@@ -223,8 +223,8 @@ def test_sharded_signed_build_bit_exact():
     spec = _spec()
     params = cs.init_params(spec, jax.random.key(7))
     items, freqs = _stream(n=256, seed=7)
-    from jax.sharding import Mesh
-    mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("d",))
     delta = dist.sharded_signed_build(
         spec, params, mesh, ("d",), jnp.asarray(items), jnp.asarray(freqs),
         table_dtype=jnp.int32)
