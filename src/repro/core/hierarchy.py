@@ -82,6 +82,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import sketch as sk
 from repro.core.hashing import KeySchema
 
@@ -718,16 +719,26 @@ def batched_candidate_estimates(
     if min(counts) == 0:
         raise ValueError("every request must have a non-empty prefix set "
                          "(callers retire empty requests before batching)")
-    values = jnp.asarray(np.asarray(values, dtype=np.uint32))
-    if use_kernel:
-        state = _one_replica(state)
-    cat = np.concatenate(
-        [np.asarray(p, dtype=np.uint32) for p in prefix_sets], axis=0)
-    pp_all, cp = candidate_partials(hspec, state, level,
-                                    jnp.asarray(_pad_rows_pow2(cat)), values)
-    # pad P_max to a power of two too: one compiled launch per bucket
-    nq, c = len(counts), int(cp.shape[1])
-    p_max = 1 << max(0, (max(counts) - 1).bit_length())
+    with obs.span("sketch.partials", level=level):
+        values = jnp.asarray(np.asarray(values, dtype=np.uint32))
+        if use_kernel:
+            state = _one_replica(state)
+        cat = np.concatenate(
+            [np.asarray(p, dtype=np.uint32) for p in prefix_sets], axis=0)
+        pp_all, cp = candidate_partials(
+            hspec, state, level, jnp.asarray(_pad_rows_pow2(cat)), values)
+        # pad P_max to a power of two too: one compiled launch per bucket
+        nq, c = len(counts), int(cp.shape[1])
+        p_max = 1 << max(0, (max(counts) - 1).bit_length())
+        # per-request column blocks, padded to the common P_max
+        blocks, off = [], 0
+        for n in counts:
+            blk = pp_all[:, off : off + n]
+            if n < p_max:
+                blk = jnp.pad(blk, ((0, 0), (0, p_max - n)))
+            blocks.append(blk)
+            off += n
+        pp3 = jnp.stack(blocks, axis=1)              # [w, Q, P_max]
 
     table = state.states[level].table
     from repro.kernels.hier_query import (
@@ -744,20 +755,13 @@ def batched_candidate_estimates(
         def one(pp3):
             return hier_candidate_query_batched_ref(table, pp3, cp)
 
-    # per-request column blocks, padded to the common P_max
-    blocks, off = [], 0
-    for n in counts:
-        blk = pp_all[:, off : off + n]
-        if n < p_max:
-            blk = jnp.pad(blk, ((0, 0), (0, p_max - n)))
-        blocks.append(blk)
-        off += n
-    pp3 = jnp.stack(blocks, axis=1)                  # [w, Q, P_max]
-
     if max_batch is None or nq * p_max * c <= max_batch:
-        grids = np.asarray(one(pp3))
+        q_chunk, launches = nq, 1
+        with obs.span("sketch.grid_readback", level=level):
+            grids = np.asarray(one(pp3))
     else:
         q_chunk = max(1, max_batch // max(p_max * c, 1))
+        launches = -(-nq // q_chunk)
         outs = []
         for s in range(0, nq, q_chunk):
             qc = pp3[:, s : s + q_chunk]
@@ -766,8 +770,12 @@ def batched_candidate_estimates(
                 # every chunk (pad prefix 0 is a valid cell; sliced off)
                 qc = jnp.pad(qc, ((0, 0), (0, q_chunk - qc.shape[1]),
                                   (0, 0)))
-            outs.append(np.asarray(one(qc)))
+            with obs.span("sketch.grid_readback", level=level):
+                outs.append(np.asarray(one(qc)))
         grids = np.concatenate(outs, axis=0)[:nq]
+    obs.count("sketch.grid_launches", launches)
+    obs.count("sketch.grid_lanes", launches * q_chunk * p_max * c)
+    obs.count("sketch.grid_lanes_useful", sum(counts) * c)
     return [grids[i, : counts[i], :] for i in range(nq)]
 
 
@@ -821,18 +829,21 @@ def batched_find_heavy_hitters(
         grids = batched_candidate_estimates(
             hspec, state, lvl, [prefixes[q] for q in active], cand,
             use_kernel=use_kernel, interpret=interpret, max_batch=max_batch)
-        for q, grid in zip(active, grids):
-            grid = grid.astype(np.int64)
-            keep_p, keep_c = np.nonzero(grid >= thrs[q])
-            prefixes[q] = np.concatenate(
-                [prefixes[q][keep_p], cand[keep_c]], axis=1)
-            est[q] = grid[keep_p, keep_c]
+        with obs.span("sketch.prune", level=lvl):
+            for q, grid in zip(active, grids):
+                grid = grid.astype(np.int64)
+                keep_p, keep_c = np.nonzero(grid >= thrs[q])
+                prefixes[q] = np.concatenate(
+                    [prefixes[q][keep_p], cand[keep_c]], axis=1)
+                est[q] = grid[keep_p, keep_c]
 
     out: List[Tuple[np.ndarray, np.ndarray]] = []
-    for q in range(nq):
-        if done[q] is not None:
-            out.append(done[q])
-            continue
-        order = np.argsort(-est[q], kind="stable")
-        out.append((hspec.to_schema_order(prefixes[q][order]), est[q][order]))
+    with obs.span("sketch.prune"):
+        for q in range(nq):
+            if done[q] is not None:
+                out.append(done[q])
+                continue
+            order = np.argsort(-est[q], kind="stable")
+            out.append((hspec.to_schema_order(prefixes[q][order]),
+                        est[q][order]))
     return out

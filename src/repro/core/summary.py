@@ -30,6 +30,8 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
+from repro import obs
+
 Row = Tuple[int, ...]
 
 
@@ -58,13 +60,17 @@ class SpaceSaving:
         if freqs is None:
             freqs = np.ones(values.shape[0], dtype=np.int64)
         freqs = np.asarray(freqs, dtype=np.float64)
-        # aggregate the block first: one summary op per *distinct* row
-        uniq, inv = np.unique(values, axis=0, return_inverse=True)
-        tot = np.bincount(inv.reshape(-1), weights=freqs)
-        for row, f in zip(uniq.tolist(), tot.tolist()):
-            if f <= 0:
-                continue  # zero-weight pad rows are not observations
-            self._insert(tuple(row), float(f))
+        with obs.span("sketch.pool_offer", rows=values.shape[0]):
+            # aggregate the block first: one summary op per *distinct* row
+            uniq, inv = np.unique(values, axis=0, return_inverse=True)
+            tot = np.bincount(inv.reshape(-1), weights=freqs)
+            evicted = 0
+            for row, f in zip(uniq.tolist(), tot.tolist()):
+                if f <= 0:
+                    continue  # zero-weight pad rows are not observations
+                evicted += self._insert(tuple(row), float(f))
+        obs.count("sketch.pool_offered", uniq.shape[0])
+        obs.count("sketch.pool_evicted", evicted)
 
     def _pop_min(self) -> Tuple[float, Row]:
         """Pop the live minimum-count entry, discarding stale heap entries."""
@@ -73,7 +79,9 @@ class SpaceSaving:
             if self._count.get(row) == c:
                 return c, row
 
-    def _insert(self, row: Row, f: float) -> None:
+    def _insert(self, row: Row, f: float) -> bool:
+        """Add ``f`` to ``row``; True when that evicted another row."""
+        evicted = False
         if row in self._count:
             self._count[row] += f
         elif len(self._count) < self.capacity:
@@ -85,9 +93,11 @@ class SpaceSaving:
             del self._err[victim]
             self._count[row] = floor + f
             self._err[row] = floor
+            evicted = True
         heapq.heappush(self._heap, (self._count[row], row))
         if len(self._heap) > 4 * self.capacity:
             self._compact_heap()
+        return evicted
 
     def _compact_heap(self) -> None:
         """Drop stale entries (bounds the heap at O(capacity) regardless of

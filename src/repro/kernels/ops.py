@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import countsketch as cskt
 from repro.core import sketch as sk
 from repro.kernels.hashes import make_plan
@@ -543,33 +544,41 @@ class KernelHierarchy:
         """Fold a weighted block: one fused launch per fixed-size sub-block."""
         items = np.asarray(items, dtype=np.uint32)
         freqs = np.asarray(freqs)
-        if self.mode == "signed":
-            check_signed_kernel_freqs(freqs, self.table.dtype)
-        else:
-            check_linear_kernel_freqs(freqs, self.table.dtype)
+        with obs.span("sketch.check"):
+            if self.mode == "signed":
+                check_signed_kernel_freqs(freqs, self.table.dtype)
+            else:
+                check_linear_kernel_freqs(freqs, self.table.dtype)
         schema = self.hspec.levels[-1].schema
         n_fine = self.hspec.n_levels - 1
         b = self.block_b
+        launches = -(-items.shape[0] // b)
         for s in range(0, items.shape[0], b):
-            blk_i = items[s : s + b]
-            blk_f = freqs[s : s + b]
-            if blk_i.shape[0] < b:
-                pad = b - blk_i.shape[0]
-                blk_i = np.pad(blk_i, ((0, pad), (0, 0)))
-                blk_f = np.pad(blk_f, (0, pad))
-            # group-major column order = the finest level's chunk layout
-            ordered = np.asarray(self.hspec.level_items(n_fine, blk_i))
-            chunks = schema.module_chunks(jnp.asarray(ordered))
-            if self.mode == "signed":
-                self.table = hier_update_signed_pallas(
-                    self.hplan, self.table, chunks, jnp.asarray(blk_f),
-                    self.params.q, self.params.r,
-                    self.cs_params.sign_q, self.cs_params.sign_r,
-                    interpret=self.interpret,
-                )
-            else:
-                self.table = hier_update_pallas(
-                    self.hplan, self.table, chunks, jnp.asarray(blk_f),
-                    self.params.q, self.params.r, interpret=self.interpret,
-                )
+            with obs.span("sketch.fold_prep"):
+                blk_i = items[s : s + b]
+                blk_f = freqs[s : s + b]
+                if blk_i.shape[0] < b:
+                    pad = b - blk_i.shape[0]
+                    blk_i = np.pad(blk_i, ((0, pad), (0, 0)))
+                    blk_f = np.pad(blk_f, (0, pad))
+                # group-major column order = the finest level's chunk layout
+                ordered = np.asarray(self.hspec.level_items(n_fine, blk_i))
+                chunks = schema.module_chunks(jnp.asarray(ordered))
+                blk_f = jnp.asarray(blk_f)
+            with obs.span("sketch.fold_launch"):
+                if self.mode == "signed":
+                    self.table = hier_update_signed_pallas(
+                        self.hplan, self.table, chunks, blk_f,
+                        self.params.q, self.params.r,
+                        self.cs_params.sign_q, self.cs_params.sign_r,
+                        interpret=self.interpret,
+                    )
+                else:
+                    self.table = hier_update_pallas(
+                        self.hplan, self.table, chunks, blk_f,
+                        self.params.q, self.params.r,
+                        interpret=self.interpret,
+                    )
+        obs.count("sketch.fold_launches", launches)
+        obs.count("sketch.fold_pad_rows", launches * b - items.shape[0])
         self._state_cache = None

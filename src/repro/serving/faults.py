@@ -134,7 +134,7 @@ class FaultPlan:
     ``corrupt_newest_snapshot``: before each recovery, byte-flip an array
     in the newest snapshot so recovery must CRC-fail it and fall back.
     ``straggle_op`` / ``straggle_seconds``: sleep before that operation,
-    feeding the straggler monitor an outlier step time.
+    a host stall that a profiler trace of the run shows (repro.obs).
     """
     crash_after_ops: Optional[int] = None
     max_crashes: int = 1
@@ -151,10 +151,9 @@ class FaultPlan:
 
 @dataclasses.dataclass
 class SupervisedRunReport:
-    """What happened across one supervised run: crashes, recoveries, timing."""
+    """What happened across one supervised run: crashes and recoveries."""
     crashes: int
     recoveries: List[rec.RecoveryReport]
-    op_times: List[float]               # per-op wall time (straggler feed)
 
 
 class ServingSupervisor:
@@ -197,7 +196,6 @@ class ServingSupervisor:
         fault = fault or FaultPlan()
         restarts = 0
         recoveries: List[rec.RecoveryReport] = []
-        op_times: List[float] = []
         engine, report = self._build()
         recoveries.append(report)
         while True:
@@ -212,7 +210,6 @@ class ServingSupervisor:
                         raise InjectedCrash(f"killed before op {i}")
                     if fault.straggle_op == i and fault.straggle_seconds:
                         time.sleep(fault.straggle_seconds)
-                    t0 = time.perf_counter()
                     op = ops[i]
                     if op[0] == "block":
                         engine.ingest(op[1], op[2])
@@ -220,7 +217,6 @@ class ServingSupervisor:
                         engine.advance()
                     else:
                         raise ValueError(f"unknown op kind {op[0]!r}")
-                    op_times.append(time.perf_counter() - t0)
                     ops_this_life += 1
                 break
             except InjectedCrash:
@@ -244,4 +240,4 @@ class ServingSupervisor:
                 engine, report = self._build()
                 recoveries.append(report)
         return engine, SupervisedRunReport(
-            crashes=restarts, recoveries=recoveries, op_times=op_times)
+            crashes=restarts, recoveries=recoveries)

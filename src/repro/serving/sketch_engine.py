@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.serving.migration import MigratingSurface, require_not_migrating
 
 
@@ -154,12 +155,15 @@ class SketchTopKEndpoint(MigratingSurface):
         """Fold one normalized block into the ACTIVE (serving) tables."""
         if self.mode == "conservative":
             from repro.core.sketch import check_conservative_freqs
-            check_conservative_freqs(freqs, self.state.states[0].table.dtype)
+            with obs.span("sketch.check"):
+                check_conservative_freqs(freqs,
+                                         self.state.states[0].table.dtype)
         if self._kh is not None:
             # reject kernel-unrepresentable weights BEFORE touching pools
             # or totals, so a failed ingest leaves the endpoint unchanged
             from repro.kernels.ops import check_linear_kernel_freqs
-            check_linear_kernel_freqs(freqs, self._kh.table.dtype)
+            with obs.span("sketch.check"):
+                check_linear_kernel_freqs(freqs, self._kh.table.dtype)
         self.total += int(freqs.sum())
         for j, g in enumerate(self.hspec.base.partition):
             self._pools[j].offer(items[:, list(g)], freqs)
@@ -172,11 +176,15 @@ class SketchTopKEndpoint(MigratingSurface):
         # update compiles O(log B) variants, not one per block length
         # (zero-frequency pad items are no-ops and stay out of the pools)
         from repro.core.distributed import pad_block_pow2
+        n = items.shape[0]
         items, freqs, _ = pad_block_pow2(items, freqs, 1)
         fold = (self._hh.update_conservative_jit
                 if self.mode == "conservative" else self._hh.update_jit)
-        self.state = fold(self.hspec, self.state, jnp.asarray(items),
-                          jnp.asarray(freqs))
+        with obs.span("sketch.fold_launch"):
+            self.state = fold(self.hspec, self.state, jnp.asarray(items),
+                              jnp.asarray(freqs))
+        obs.count("sketch.fold_launches")
+        obs.count("sketch.fold_pad_rows", items.shape[0] - n)
 
     def ingest(self, items: np.ndarray,
                freqs: Optional[np.ndarray] = None) -> None:
@@ -246,8 +254,12 @@ class SketchTopKEndpoint(MigratingSurface):
         for j, g in enumerate(self.hspec.base.partition):
             self._pools[j].offer(staged.raw_items[:, list(g)],
                                  staged.raw_freqs)
-        self._state = self._hh.fold_indices(self._state, staged.idxs,
-                                            staged.freqs)
+        with obs.span("sketch.fold_launch"):
+            self._state = self._hh.fold_indices(self._state, staged.idxs,
+                                                staged.freqs)
+        obs.count("sketch.fold_launches")
+        obs.count("sketch.fold_pad_rows",
+                  staged.freqs.shape[0] - staged.raw_freqs.shape[0])
         self._migration_tick(staged.raw_items, staged.raw_freqs)
 
     def candidates(self) -> List[np.ndarray]:
@@ -588,10 +600,12 @@ class SketchServeEngine:
     def ingest(self, items: np.ndarray,
                freqs: Optional[np.ndarray] = None) -> None:
         """Ingest one weighted block (pipelined where the backend allows)."""
-        with self._lock:
-            items = np.asarray(items, dtype=np.uint32)
-            if items.shape[0] == 0:
-                return
+        items = np.asarray(items, dtype=np.uint32)
+        if items.shape[0] == 0:
+            return
+        with self._lock, obs.span("sketch.ingest", rows=items.shape[0]):
+            obs.count("sketch.blocks")
+            obs.count("sketch.rows", items.shape[0])
             if freqs is None:
                 freqs = np.ones(items.shape[0], dtype=np.int64)
             freqs = np.asarray(freqs)
@@ -608,7 +622,8 @@ class SketchServeEngine:
                     # backend's serving tables WITHOUT refreshing the
                     # engine snapshot (that stays on the staleness clock)
                     self._fold_pending()
-                    self.backend.sync()
+                    with obs.span("sketch.psum"):
+                        self.backend.sync()
                     self._blocks_since_psum = 0
 
     def _fold_pending(self) -> None:
@@ -640,14 +655,18 @@ class SketchServeEngine:
         from repro.core import sketch as sk
 
         b = self.backend
-        st = b.state
-        if callable(st):                     # sharded/windowed expose a method
-            st = st()
-        state = hh.HierarchyState(states=tuple(
-            sk.SketchState(params=s.params, table=jnp.array(s.table))
-            for s in st.states))
+        with obs.span("sketch.snapshot"):
+            st = b.state
+            if callable(st):                 # sharded/windowed expose a method
+                st = st()
+            state = hh.HierarchyState(states=tuple(
+                sk.SketchState(params=s.params, table=jnp.array(s.table))
+                for s in st.states))
+            with obs.span("sketch.candidates"):
+                candidates = b.candidates()
+        obs.count("sketch.snapshots")
         return SketchSnapshot(hspec=b.hspec, state=state,
-                              candidates=b.candidates(),
+                              candidates=candidates,
                               total=int(b.total), mass=self._mass)
 
     @property
@@ -683,7 +702,8 @@ class SketchServeEngine:
         with self._lock:
             self._fold_pending()
             if self._is_sharded:
-                self.backend.sync()
+                with obs.span("sketch.psum"):
+                    self.backend.sync()
                 self._blocks_since_psum = 0
             self._snap = self._take_snapshot()
             if self.tuner is not None:
@@ -768,8 +788,11 @@ class SketchServeEngine:
             reqs, self._queue = self._queue, []
             if not reqs:
                 return []
-            snap = self._fresh_snapshot()
-            self._serve_batched(snap, reqs)
+            with obs.span("sketch.flush", requests=len(reqs)):
+                obs.count("sketch.flushes")
+                obs.count("sketch.requests", len(reqs))
+                snap = self._fresh_snapshot()
+                self._serve_batched(snap, reqs)
             return reqs
 
     def _serve_batched(self, snap: SketchSnapshot,
@@ -808,10 +831,14 @@ class SketchServeEngine:
                 r.done = True
             else:
                 pending.append(r)
+        rnd = 0
         while pending:
-            results = hh.batched_find_heavy_hitters(
-                snap.hspec, snap.state, [thr[r.rid] for r in pending],
-                snap.candidates, use_kernel=self.backend.use_kernel)
+            obs.count("sketch.descent_rounds", len(pending))
+            with obs.span("sketch.descent_round", round=rnd,
+                          requests=len(pending)):
+                results = hh.batched_find_heavy_hitters(
+                    snap.hspec, snap.state, [thr[r.rid] for r in pending],
+                    snap.candidates, use_kernel=self.backend.use_kernel)
             nxt = []
             for r, (items, est) in zip(pending, results):
                 if r.kind == "heavy_hitters":
@@ -822,3 +849,4 @@ class SketchServeEngine:
                     thr[r.rid] = max(floor[r.rid], thr[r.rid] // 4)
                     nxt.append(r)
             pending = nxt
+            rnd += 1

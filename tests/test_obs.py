@@ -1,0 +1,175 @@
+"""repro.obs: the counter table, spans on the profiler's clock, and the
+counters the serving path keeps (blocks, rows, descent rounds, evictions)."""
+import glob
+
+import numpy as np
+import pytest
+
+import jax
+
+from repro import obs
+from repro.core import sketch as sk
+from repro.core.summary import SpaceSaving
+from repro.serving.sharded_topk import threshold_descent_topk
+from repro.serving.sketch_engine import SketchServeEngine, SketchTopKEndpoint
+from repro.streams import zipf_hh_workload
+
+KEY = jax.random.PRNGKey(0)
+
+
+def _delta(before):
+    return {k: v - before.get(k, 0) for k, v in obs.counters().items()
+            if v != before.get(k, 0)}
+
+
+def _stream(seed=3):
+    return zipf_hh_workload(n_src=100, n_tgt=200, n_edges=800,
+                            n_occurrences=4_000, seed=seed).stream
+
+
+def _blocks(stream, size):
+    it, fr = stream.items, stream.freqs
+    return [(it[s:s + size], fr[s:s + size])
+            for s in range(0, it.shape[0], size)]
+
+
+def test_counters_add_and_copy():
+    before = obs.counters()
+    obs.count("test.obs.a")
+    obs.count("test.obs.a", 4)
+    obs.count("test.obs.b", np.int64(2))
+    got = obs.counters()
+    assert _delta(before) == {"test.obs.a": 5, "test.obs.b": 2}
+    assert type(got["test.obs.b"]) is int
+    got["test.obs.a"] += 100                 # a copy: the table is untouched
+    assert obs.counters()["test.obs.a"] == got["test.obs.a"] - 100
+
+
+def _host_events(trace_dir):
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("test.obs."):
+                        events[e.name] = (e.start_ns, e.end_ns,
+                                          dict(e.stats))
+    return events
+
+
+def test_spans_nest_on_the_profiler_clock_and_are_inert_outside(tmp_path):
+    with obs.span("test.obs.before", rows=1):      # no session: inert
+        pass
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("test.obs.outer", rows=4096):
+            with obs.span("test.obs.inner", level=2):
+                jax.numpy.ones(8).block_until_ready()
+    ev = _host_events(tmp_path)
+    assert set(ev) == {"test.obs.outer", "test.obs.inner"}
+    (os_, oe, oargs), (is_, ie, iargs) = ev["test.obs.outer"], \
+        ev["test.obs.inner"]
+    assert os_ <= is_ < ie <= oe
+    assert oargs == {"rows": 4096} and iargs == {"level": 2}
+
+
+def _endpoint(stream, capacity=1 << 16):
+    spec = sk.mod_sketch_spec(stream.schema, [(0,), (1,)], (16, 64), 4)
+    return SketchTopKEndpoint(spec, KEY, max_candidates_per_group=capacity)
+
+
+def test_engine_counts_the_blocks_and_rows_fed():
+    stream = _stream()
+    eng = SketchServeEngine(_endpoint(stream), max_staleness=0)
+    blocks = _blocks(stream, 300)
+    before = obs.counters()
+    for items, freqs in blocks:
+        eng.ingest(items, freqs)
+    eng.ingest(stream.items[:0], stream.freqs[:0])     # empty: not a block
+    eng.submit_heavy_hitters(50)
+    eng.flush()                              # refreshes the snapshot
+    got = _delta(before)
+    assert got["sketch.snapshots"] == 1
+    assert got["sketch.blocks"] == len(blocks)
+    assert got["sketch.rows"] == stream.items.shape[0]
+    assert got["sketch.fold_launches"] == len(blocks)
+    pad = sum((1 << (len(f) - 1).bit_length()) - len(f) for _, f in blocks)
+    assert got.get("sketch.fold_pad_rows", 0) == pad
+
+
+def test_descent_rounds_are_the_thresholds_the_serial_descent_tries():
+    stream = _stream()
+    ep = _endpoint(stream)
+    for items, freqs in _blocks(stream, 256):
+        ep.ingest(items, freqs)
+    eng = SketchServeEngine(ep, max_staleness=0)
+    ks, hh_thresholds = [1, 5, 20, 200], [40, ep.total + 1]
+    tried = 0
+
+    def counting(thr, candidates):
+        nonlocal tried
+        tried += 1
+        return ep.heavy_hitters(thr, candidates)
+
+    for k in ks:
+        threshold_descent_topk(counting, ep.candidates(), k, total=ep.total,
+                               n_modules=ep.hspec.base.schema.modularity)
+    before = obs.counters()
+    for k in ks:
+        eng.submit_topk(k)
+    for t in hh_thresholds:
+        eng.submit_heavy_hitters(t)
+    eng.flush()
+    got = _delta(before)
+    assert got["sketch.descent_rounds"] == tried + len(hh_thresholds)
+    assert got["sketch.requests"] == len(ks) + len(hh_thresholds)
+    assert got["sketch.flushes"] == 1
+    assert got["sketch.grid_lanes"] >= got["sketch.grid_lanes_useful"] > 0
+    assert got["sketch.grid_launches"] >= 1
+
+
+def test_pool_evictions_match_a_pool_driven_row_by_row():
+    stream = _stream(seed=7)
+    capacity = 24
+    pool, brute = SpaceSaving(capacity, 1), SpaceSaving(capacity, 1)
+    want = got = offered = 0
+    for items, freqs in _blocks(stream, 150):
+        values = items[:, :1]
+        before = obs.counters()
+        pool.offer(values, freqs)
+        d = _delta(before)
+        got += d.get("sketch.pool_evicted", 0)
+        offered += d["sketch.pool_offered"]
+        # the same distinct rows and weights, one offer each, in the
+        # order the block's offer takes them
+        uniq, inv = np.unique(values, axis=0, return_inverse=True)
+        tot = np.bincount(inv.reshape(-1), weights=freqs)
+        for row, f in zip(uniq, tot):
+            if f > 0:
+                want += (len(brute) == capacity
+                         and tuple(row.tolist()) not in brute.counts())
+                brute.offer(row[None], np.asarray([f]))
+    assert want > 0
+    assert got == want
+    assert pool.counts() == brute.counts()
+    assert offered >= len(pool)
+
+
+@pytest.mark.parametrize("capacity", [8, 1 << 10])
+def test_pool_evictions_are_new_rows_less_the_pool_growth(capacity):
+    stream = _stream(seed=11)
+    pool = SpaceSaving(capacity, 2)
+    for items, freqs in _blocks(stream, 200):
+        seen = set(pool.counts())
+        n0 = len(pool)
+        before = obs.counters()
+        pool.offer(items, freqs)
+        new = {tuple(r) for r in items.tolist()} - seen
+        evicted = _delta(before).get("sketch.pool_evicted", 0)
+        # rows new to the pool either filled free room or evicted another
+        # (a row evicted and re-admitted within the block counts twice)
+        assert evicted >= len(new) - (len(pool) - n0)
+        if len(pool) < capacity:
+            assert evicted == 0
