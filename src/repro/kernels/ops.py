@@ -561,10 +561,11 @@ class KernelHierarchy:
                     pad = b - blk_i.shape[0]
                     blk_i = np.pad(blk_i, ((0, pad), (0, 0)))
                     blk_f = np.pad(blk_f, (0, pad))
-                # group-major column order = the finest level's chunk layout
-                ordered = np.asarray(self.hspec.level_items(n_fine, blk_i))
-                chunks = schema.module_chunks(jnp.asarray(ordered))
-                blk_f = jnp.asarray(blk_f)
+                # group-major column order = the finest level's chunk layout;
+                # digits split on the host, so the jitted fold below is the
+                # sub-block's one dispatch (its argument transfer included)
+                chunks = schema.module_chunks_np(
+                    self.hspec.level_items(n_fine, blk_i))
             with obs.span("sketch.fold_launch"):
                 if self.mode == "signed":
                     self.table = hier_update_signed_pallas(

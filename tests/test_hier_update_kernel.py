@@ -8,15 +8,20 @@ multi-level Pallas kernel vs the per-level jnp reference on int32 and f32
 tables (duplicate keys, non-tile-multiple level widths, zero-frequency pad
 rows), the endpoint's fused-ingest path, and descent guarantees under the
 shared params."""
+import logging
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
+from repro.core import countsketch as cs
 from repro.core import hierarchy as hh
 from repro.core import sketch as sk
 from repro.core.hashing import KeySchema
 from repro.kernels import KernelHierarchy, hier_update_pallas, make_hier_plan
+from repro.kernels import ops
 from repro.launch.mesh import make_mesh
 from repro.serving.engine import SketchTopKEndpoint
 from repro.streams import zipf_hh_workload
@@ -113,24 +118,106 @@ def test_cascade_update_equals_per_level_reference():
 # Fused Pallas kernel parity
 # --------------------------------------------------------------------------
 
-def test_fused_kernel_bit_parity_int32():
-    """Acceptance: one pallas_call over the concatenated padded tables is
-    bit-identical to the per-level jnp reference on int32 tables, with
-    duplicate keys and non-tile-multiple level widths."""
+def _reference_tables(hspec, kh, key, items, freqs):
+    """The per-level jnp oracle's tables for ``kh``'s mode, from ``key``."""
+    it, fr = jnp.asarray(items), jnp.asarray(freqs)
+    if kh.mode == "signed":
+        zero = cs.CountSketchHierarchy(kh.cs_params, tuple(
+            jnp.zeros((s.width, s.table_size), jnp.int32)
+            for s in hspec.levels))
+        return cs.hier_update_reference(hspec, zero, it, fr).tables
+    ref = hh.update_reference(hspec, hh.init_hierarchy(hspec, key), it, fr)
+    return tuple(st.table for st in ref.states)
+
+
+def _kernel_tables(kh):
+    if kh.mode == "signed":
+        return kh.cs_state().tables
+    return tuple(st.table for st in kh.state().states)
+
+
+@pytest.mark.parametrize("n_rows", [4096, 1000, 1])
+@pytest.mark.parametrize("mode", ["linear", "signed"])
+def test_fused_kernel_bit_parity_int32(mode, n_rows):
+    """Acceptance: one pallas_call per 1,024-row sub-block over the
+    concatenated padded tables is bit-identical to the per-level jnp
+    reference on int32 tables, with duplicate keys, non-tile-multiple level
+    widths, and full (4,096) as well as tail-padded (1,000 and 1) blocks;
+    signed weights in signed mode."""
     hspec = _hier()
     key = jax.random.PRNGKey(7)
-    items, freqs = _stream(hspec, 500, seed=0)
-    kh = KernelHierarchy(hspec, key, tile_h=128, block_b=128, interpret=True)
+    items, freqs = _stream(hspec, n_rows, seed=0)
+    if mode == "signed":
+        rng = np.random.default_rng(1)
+        freqs = freqs * rng.choice(np.array([-1, 1], np.int32), n_rows)
+    kh = KernelHierarchy(hspec, key, tile_h=128, block_b=1024, mode=mode,
+                         interpret=True)
     for lvl, pad in zip(hspec.levels, kh.hplan.level_pads):
         assert lvl.table_size % 128 != 0, "cases must exercise padding"
         assert pad % 128 == 0
     kh.update(items, freqs)
 
-    ref = hh.update_reference(hspec, hh.init_hierarchy(hspec, key),
-                              jnp.asarray(items), jnp.asarray(freqs))
-    for a, b in zip(kh.state().states, ref.states):
-        np.testing.assert_array_equal(np.asarray(a.table),
-                                      np.asarray(b.table))
+    ref = _reference_tables(hspec, kh, key, items, freqs)
+    for a, b in zip(_kernel_tables(kh), ref, strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("mode", ["linear", "signed"])
+def test_fused_ingest_one_dispatch_per_sub_block(mode, monkeypatch, caplog):
+    """Each sub-block is one jitted fold fed host arrays: the key digits are
+    split on the host (``module_chunks`` never sees a concrete device
+    array), the first update compiles the fold and nothing else, and after
+    it blocks of 4,096 and 1,000 rows compile nothing at all."""
+    fold_name = ("hier_update_signed_pallas" if mode == "signed"
+                 else "hier_update_pallas")
+    real_fold = getattr(ops, fold_name)
+    launches = []
+
+    def counting_fold(hplan, table, chunks, freqs, *params, **kw):
+        launches.append((type(chunks), type(freqs), chunks.shape))
+        return real_fold(hplan, table, chunks, freqs, *params, **kw)
+
+    monkeypatch.setattr(ops, fold_name, counting_fold)
+    real_chunks = KeySchema.module_chunks
+    concrete = []
+
+    def recording_chunks(self, items):
+        if not isinstance(items, jax.core.Tracer):
+            concrete.append(type(items))
+        return real_chunks(self, items)
+
+    monkeypatch.setattr(KeySchema, "module_chunks", recording_chunks)
+
+    # table sizes and a block width of this test's own: an eager op on the
+    # path would compile on the first update and show in the log
+    hspec = _hier(ranges=(40, 24, 9))
+    b = 512
+    kh = KernelHierarchy(hspec, jax.random.PRNGKey(29), tile_h=128,
+                         block_b=b, mode=mode, interpret=True)
+    items, freqs = _stream(hspec, 4096, seed=10)
+
+    def compiled(fn):
+        caplog.clear()
+        with caplog.at_level(logging.WARNING), jax.log_compiles(True):
+            fn()
+            jax.block_until_ready(kh.table)
+        return [r.getMessage() for r in caplog.records
+                if r.getMessage().startswith("Compiling ")]
+
+    first = compiled(lambda: kh.update(items[:1000], freqs[:1000]))
+    assert all(f"jit({fold_name})" in m for m in first), first
+
+    before = obs.counters().get("sketch.fold_launches", 0)
+    del launches[:]
+    warm = compiled(lambda: (kh.update(items, freqs),
+                             kh.update(items[:1000], freqs[:1000])))
+    assert warm == []
+    n_launch = 4096 // b + -(-1000 // b)
+    assert len(launches) == n_launch
+    assert obs.counters()["sketch.fold_launches"] - before == n_launch
+    assert all(ct is np.ndarray and ft is np.ndarray and shape[0] == b
+               for ct, ft, shape in launches), launches
+    assert concrete == []
 
 
 def test_fused_kernel_bit_parity_f32_integer_weights():
