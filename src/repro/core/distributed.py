@@ -230,9 +230,9 @@ def lazy_hierarchy_update(
     (core.hierarchy.hierarchy_indices -- exact under the shared per-group
     params every ``init_hierarchy`` state carries), and scatter-adds into
     its local copy of every level's table.  The psum merge is deferred to
-    the explicit sync point (:func:`merge_local_hierarchy`).  The
-    per-device fold body is the jnp scatter-add, not the fused Pallas
-    kernel (kernels/hier_update.py), on every backend; the two are
+    the explicit sync point (:func:`sync_local_hierarchy`).  The
+    per-device fold body is the jnp scatter-add; the fused Pallas kernel's
+    counterpart is :func:`lazy_hierarchy_update_fused`, and the two are
     bit-identical by the parity tests.
 
     ``params`` keeps the one-entry-per-level shape of ``HierarchyState``
@@ -263,6 +263,58 @@ def lazy_hierarchy_update(
         check_vma=False,
     )
     return fn(tuple(local_tables), items, freqs)
+
+
+def lazy_hierarchy_update_fused(
+    hplan,                      # kernels.hier_update.HierPlan
+    mesh: Mesh,
+    data_axes: Tuple[str, ...],
+    local_tables: Sequence[jax.Array],  # one [n_shards, w, padded_cols]
+    q: jax.Array,               # uint32[w, C] shared-family multipliers
+    r: jax.Array,               # uint32[w, m] shared-family offsets
+    chunks: jax.Array,          # uint32[n_shards * per, C] key digits
+    freqs: jax.Array,           # [n_shards * per], per % block_b == 0
+    *,
+    block_b: int,
+    interpret=None,
+) -> Tuple[jax.Array]:
+    """Lazy local fold of ALL levels through the fused Pallas kernel, in
+    one shard_map: no collective on ingest.
+
+    The kernel-layout counterpart of :func:`lazy_hierarchy_update`: the
+    local tables are ONE stack of every shard's concatenated padded level
+    table (the kernels/ops.KernelHierarchy layout), and each shard runs
+    kernels/hier_update.fold on its contiguous slice of the block, one
+    kernel launch per ``block_b`` rows (a scan over the slice's
+    sub-blocks).  The digits are split on the host
+    (kernels/ops.fold_digits), so the block is this program's only input
+    besides the tables and the params.  Bit-identical to the scatter-add
+    body and to the per-level reference on integer tables.
+    """
+    from repro.kernels.hier_update import fold as kernel_fold
+
+    (stack,) = local_tables
+
+    def fold(tbl, q_l, r_l, ch, fr):
+        k = ch.shape[0] // block_b
+
+        def sub_block(t, x):
+            return kernel_fold(hplan, t, x[0], x[1], (q_l, r_l),
+                               interpret), None
+
+        t, _ = jax.lax.scan(sub_block, tbl[0],
+                            (ch.reshape(k, block_b, ch.shape[1]),
+                             fr.reshape(k, block_b)))
+        return (t[None],)
+
+    fn = shard_map(
+        fold,
+        mesh=mesh,
+        in_specs=(P(data_axes), P(), P(), P(data_axes), P(data_axes)),
+        out_specs=(P(data_axes),),
+        check_vma=False,
+    )
+    return fn(stack, q, r, chunks, freqs)
 
 
 def sharded_hierarchy_fold(
@@ -320,6 +372,33 @@ def merge_local_hierarchy(
     return tuple(merge_local_tables(mesh, data_axes, t) for t in local_tables)
 
 
+def sync_local_hierarchy(
+    mesh: Mesh, data_axes: Tuple[str, ...],
+    local_tables: Sequence[jax.Array],
+    tables: Sequence[jax.Array],
+) -> Tuple[Tuple[jax.Array, ...], Tuple[jax.Array, ...]]:
+    """The sync point in one shard_map: every merged table plus the psum of
+    its local stack (replicated), and the local stacks zeroed (sharded as
+    they came).  Call inside a jit that donates ``local_tables``, so the
+    zeroed stacks reuse their buffers.  Exact by linearity, like
+    :func:`merge_local_hierarchy`."""
+
+    def sync(locs, tbls):
+        merged = tuple(t + jax.lax.psum(l[0], data_axes)
+                       for l, t in zip(locs, tbls))
+        return merged, tuple(jnp.zeros_like(l) for l in locs)
+
+    n = len(local_tables)
+    fn = shard_map(
+        sync,
+        mesh=mesh,
+        in_specs=((P(data_axes),) * n, (P(),) * n),
+        out_specs=((P(),) * n, (P(data_axes),) * n),
+        check_vma=False,
+    )
+    return fn(tuple(local_tables), tuple(tables))
+
+
 def init_local_tables(
     mesh: Mesh, data_axes: Tuple[str, ...],
     n_shards: int, level_shapes: Sequence[Tuple[int, ...]], dtype,
@@ -328,14 +407,25 @@ def init_local_tables(
 
     One ``[n_shards, w, h_level]`` stack per level, sharded on axis 0 over
     the mesh's data axes (the layout ``lazy_hierarchy_update`` consumes).
-    Shared by the sharded service's constructor and its N->M ``remesh``,
-    so a re-meshed service's fresh locals land on the NEW devices instead
-    of wherever the old stack happened to live.
+    Shared by the sharded service's constructor, its N->M ``remesh`` and
+    its ``load_state_dict``, so fresh locals land on the CURRENT devices
+    instead of wherever the old stack happened to live.
     """
-    return tuple(
-        jax.device_put(jnp.zeros((n_shards,) + tuple(shape), dtype=dtype),
-                       NamedSharding(mesh, P(data_axes)))
-        for shape in level_shapes)
+    shapes = tuple((n_shards,) + tuple(s) for s in level_shapes)
+    return _zeros_program(mesh, tuple(data_axes), shapes,
+                          jnp.dtype(dtype))()
+
+
+@functools.lru_cache(maxsize=32)
+def _zeros_program(mesh: Mesh, data_axes: Tuple[str, ...],
+                   shapes: Tuple[Tuple[int, ...], ...], dtype):
+    """The zeros of :func:`init_local_tables`, made in place (each device
+    its own shard, no whole stack on one device first); one compiled
+    program per mesh, shapes and dtype."""
+    def zeros():
+        return tuple(jnp.zeros(s, dtype=dtype) for s in shapes)
+
+    return jax.jit(zeros, out_shardings=NamedSharding(mesh, P(data_axes)))
 
 
 def row_sharded_query(

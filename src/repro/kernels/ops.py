@@ -112,6 +112,27 @@ def check_signed_kernel_freqs(freqs: np.ndarray, table_dtype) -> None:
             "limb split: use the core.countsketch path")
 
 
+def check_fold_freqs(mode: str, freqs: np.ndarray, table_dtype) -> None:
+    """The fused fold's frequency guard for ``mode`` ("linear" or
+    "signed").  Host prep shared by :meth:`KernelHierarchy.update` and the
+    sharded service's fused fold (serving/sharded_topk.py)."""
+    if mode == "signed":
+        check_signed_kernel_freqs(freqs, table_dtype)
+    else:
+        check_linear_kernel_freqs(freqs, table_dtype)
+
+
+def fold_digits(hspec, items: np.ndarray) -> np.ndarray:
+    """The fused fold's key operand: the finest level's 16-bit key digits,
+    uint32[B, C] in its group-major chunk layout, split by numpy on the
+    host so the fold's launch is its one dispatch.  Host prep shared by
+    :meth:`KernelHierarchy.update` (per sub-block) and the sharded
+    service's fused fold (once per block)."""
+    schema = hspec.levels[-1].schema
+    return schema.module_chunks_np(
+        hspec.level_items(hspec.n_levels - 1, items))
+
+
 class KernelSketch:
     """Sketch whose table lives padded for the Pallas kernels."""
 
@@ -545,12 +566,7 @@ class KernelHierarchy:
         items = np.asarray(items, dtype=np.uint32)
         freqs = np.asarray(freqs)
         with obs.span("sketch.check"):
-            if self.mode == "signed":
-                check_signed_kernel_freqs(freqs, self.table.dtype)
-            else:
-                check_linear_kernel_freqs(freqs, self.table.dtype)
-        schema = self.hspec.levels[-1].schema
-        n_fine = self.hspec.n_levels - 1
+            check_fold_freqs(self.mode, freqs, self.table.dtype)
         b = self.block_b
         launches = -(-items.shape[0] // b)
         for s in range(0, items.shape[0], b):
@@ -561,11 +577,9 @@ class KernelHierarchy:
                     pad = b - blk_i.shape[0]
                     blk_i = np.pad(blk_i, ((0, pad), (0, 0)))
                     blk_f = np.pad(blk_f, (0, pad))
-                # group-major column order = the finest level's chunk layout;
-                # digits split on the host, so the jitted fold below is the
-                # sub-block's one dispatch (its argument transfer included)
-                chunks = schema.module_chunks_np(
-                    self.hspec.level_items(n_fine, blk_i))
+                # the jitted fold below is the sub-block's one dispatch
+                # (its argument transfer included)
+                chunks = fold_digits(self.hspec, blk_i)
             with obs.span("sketch.fold_launch"):
                 if self.mode == "signed":
                     self.table = hier_update_signed_pallas(
@@ -582,4 +596,10 @@ class KernelHierarchy:
                     )
         obs.count("sketch.fold_launches", launches)
         obs.count("sketch.fold_pad_rows", launches * b - items.shape[0])
+        self._state_cache = None
+
+    def adopt_table(self, table: jax.Array) -> None:
+        """Take ``table`` (this plan's concatenated padded layout, e.g. a
+        merged sum computed elsewhere) as the tables."""
+        self.table = table
         self._state_cache = None

@@ -4,18 +4,25 @@
 pipeline (core/hierarchy.py) on a data-parallel device mesh:
 
   ingest   the stream block is split over the mesh's data axes and every
-           shard folds its slice into per-level *local* tables
-           (core.distributed.lazy_hierarchy_update -- ONE shard_map over
-           all levels: each item is hashed once and every level's cell
-           derived by the mixed-radix cascade; no collective on the
-           ingest hot path, local tables donated into the jitted fold),
-           while per-shard space-saving pools (core/summary.py) admit
-           candidate group values;
-  sync     at explicit sync points the local tables are psum-merged per
-           level (core.distributed.merge_local_hierarchy -- exact by
-           linearity) into the serving snapshot, and the shard pools fold
-           into global pools with the mergeable-summaries rule
-           (SpaceSaving.fold);
+           shard folds its slice into its *local* tables -- ONE shard_map
+           over all levels (``sharded_fold``): each item is hashed once
+           and every level's cell derived by the mixed-radix cascade; no
+           collective on the ingest hot path, local tables donated into
+           the jitted fold -- while per-shard space-saving pools
+           (core/summary.py) admit candidate group values.  The fold body
+           is the jnp scatter-add per level
+           (core.distributed.lazy_hierarchy_update), or with
+           ``use_update_kernel=True`` the fused Pallas kernel on one stack
+           of concatenated padded level tables
+           (core.distributed.lazy_hierarchy_update_fused, the
+           kernels/ops.KernelHierarchy layout; digits split on the host
+           once per block);
+  sync     at explicit sync points one program (``sharded_merge``,
+           core.distributed.sync_local_hierarchy) adds the psum of the
+           local tables to the merged tables (exact by linearity) and
+           returns the locals zeroed; the shard pools fold into global
+           pools with the mergeable-summaries rule (SpaceSaving.fold) at
+           the next ``candidates()``;
   query    ``heavy_hitters`` / ``topk`` run the recursive descent
            (core.hierarchy.find_heavy_hitters, optionally the Pallas
            candidate kernel kernels/hier_query.py) against the merged
@@ -43,7 +50,9 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
+from repro import obs
 from repro.core import distributed as dist
 from repro.core import hierarchy as hh
 from repro.core import sketch as sk
@@ -97,6 +106,16 @@ class ShardedTopKService(MigratingSurface):
     shape).  Pass ``sync_every=None`` for fully manual sync points; any
     query forces a sync first, so results are never stale.
 
+    ``use_update_kernel=True`` folds through the fused Pallas kernel, as
+    the one-chip endpoint's option of the same name does: every table is
+    kept in the kernels/ops.KernelHierarchy layout (the merged tables as
+    one replicated ``[w, padded_cols]`` stack, the locals as one
+    ``[n_shards, w, padded_cols]`` stack placed shard-per-device), each
+    shard's slice is padded to whole kernel launches (the
+    KernelHierarchy's ``block_b``, 1,024 rows), and ``merged`` /
+    ``state()`` is the per-level view, sliced and unpadded, cached until
+    the tables change.  Linear mode only.
+
     Hot spec migration (serving/migration.py): ``begin_migration`` opens
     a double-write window onto a successor service on the same mesh;
     queries serve from the old tables until the successor has absorbed
@@ -109,11 +128,15 @@ class ShardedTopKService(MigratingSurface):
                  data_axes: Optional[Tuple[str, ...]] = None,
                  max_candidates_per_group: int = 1 << 16,
                  sync_every: Optional[int] = 1,
-                 use_kernel: bool = False, dtype=jnp.int32,
-                 mode: str = "linear"):
+                 use_kernel: bool = False, use_update_kernel: bool = False,
+                 dtype=jnp.int32, mode: str = "linear"):
         dist.require_linear(mode, "ShardedTopKService")
         from repro.launch.mesh import sketch_data_axes
 
+        if use_update_kernel and mode != "linear":
+            raise ValueError(
+                "the fused update kernel is linear-only: fold a signed "
+                "service on the jnp path (use_update_kernel=False)")
         self.mode = mode
         self.mesh = mesh
         if data_axes is None:
@@ -122,12 +145,17 @@ class ShardedTopKService(MigratingSurface):
         self.n_shards = int(np.prod([mesh.shape[a] for a in self.data_axes],
                                     dtype=np.int64))
         self.hspec = hh.HierarchySpec.from_spec(base_spec)
-        self.merged = hh.init_hierarchy(self.hspec, key, dtype=dtype)
-        self._local = tuple(
-            jnp.zeros((self.n_shards,) + st.table.shape, dtype=dtype)
-            for st in self.merged.states)
+        state = hh.init_hierarchy(self.hspec, key, dtype=dtype)
+        self._kh, self._merged = None, None
+        if use_update_kernel:
+            from repro.kernels.ops import KernelHierarchy
+
+            self._kh = KernelHierarchy.from_state(self.hspec, state)
+        self.merged = state
+        self._local = self._zero_locals()
         self.max_candidates = int(max_candidates_per_group)
         self.use_kernel = use_kernel
+        self.use_update_kernel = bool(use_update_kernel)
         self.sync_every = sync_every
         self._dtype = dtype
         self._migration = None
@@ -159,20 +187,74 @@ class ShardedTopKService(MigratingSurface):
         table per block.  ``ingest`` rebinds self._local to the result,
         which is the only live reference.
 
-        The lambdas close over ``self.mesh``/``self.data_axes`` *at trace
-        time*, so anything that changes the mesh (``remesh``) MUST call
-        this again -- reusing the old function objects would silently
+        The functions close over ``self.mesh``/``self.data_axes`` *at
+        trace time*, so anything that changes the mesh (``remesh``) MUST
+        call this again -- reusing the old function objects would silently
         replay executables compiled for the old device set (the same
-        staleness hazard migration's ``_adopt`` documents).
+        staleness hazard migration's ``_adopt`` documents).  They are
+        named (``jit_sharded_fold``, ``jit_sharded_merge`` in a device
+        trace) so their programs can be told apart.
         """
-        self._fold = jax.jit(
-            lambda local, params, it, fr: dist.lazy_hierarchy_update(
-                self.hspec, self.mesh, self.data_axes, local, params,
-                it, fr),
-            donate_argnums=(0,))
-        self._merge = jax.jit(
-            lambda local: dist.merge_local_hierarchy(
-                self.mesh, self.data_axes, local))
+        hspec, mesh, axes, kh = self.hspec, self.mesh, self.data_axes, self._kh
+
+        if kh is None:
+            def sharded_fold(local, params, it, fr):
+                return dist.lazy_hierarchy_update(hspec, mesh, axes, local,
+                                                  params, it, fr)
+        else:
+            hplan, block, interpret = kh.hplan, kh.block_b, kh.interpret
+
+            def sharded_fold(local, params, chunks, fr):
+                return dist.lazy_hierarchy_update_fused(
+                    hplan, mesh, axes, local, params.q, params.r, chunks, fr,
+                    block_b=block, interpret=interpret)
+
+        def sharded_merge(local, tables):
+            return dist.sync_local_hierarchy(mesh, axes, local, tables)
+
+        self._fold = jax.jit(sharded_fold, donate_argnums=(0,))
+        self._merge = jax.jit(sharded_merge, donate_argnums=(0,))
+
+    # -- table layout (per level, or the fused kernel's one stack) ----------
+
+    @property
+    def merged(self) -> hh.HierarchyState:
+        """The merged (serving) tables as a HierarchyState; with the fused
+        kernel a per-level view of the stack, cached until it changes."""
+        return self._merged if self._kh is None else self._kh.state()
+
+    @merged.setter
+    def merged(self, state: hh.HierarchyState) -> None:
+        """Adopt ``state`` as the merged tables, placed replicated on the
+        mesh once here, so the fold and merge programs never re-send the
+        params or the tables to every device."""
+        rep = NamedSharding(self.mesh, PartitionSpec())
+        if self._kh is None:
+            self._merged = jax.device_put(state, rep)
+        else:
+            self._kh.load_state(state)
+            self._kh.params = jax.device_put(self._kh.params, rep)
+            self._kh.adopt_table(jax.device_put(self._kh.table, rep))
+
+    def _merged_tables(self) -> Tuple[jax.Array, ...]:
+        if self._kh is None:
+            return tuple(st.table for st in self._merged.states)
+        return (self._kh.table,)
+
+    def _set_merged_tables(self, tables: Sequence[jax.Array]) -> None:
+        if self._kh is None:
+            self._merged = hh.HierarchyState(states=tuple(
+                sk.SketchState(params=st.params, table=t)
+                for st, t in zip(self._merged.states, tables)))
+        else:
+            self._kh.adopt_table(tables[0])
+
+    def _zero_locals(self) -> Tuple[jax.Array, ...]:
+        """Zeroed local stacks for the current mesh, shard-per-device."""
+        tables = self._merged_tables()
+        return dist.init_local_tables(self.mesh, self.data_axes,
+                                      self.n_shards, [t.shape for t in tables],
+                                      tables[0].dtype)
 
     # -- ingest (per-shard lazy fold, no collective) ------------------------
 
@@ -183,8 +265,10 @@ class ShardedTopKService(MigratingSurface):
         The block is padded so every shard sees the same power-of-two row
         count (zero-frequency pad rows are no-ops in the linear update and
         are skipped by the pools), then each shard folds its contiguous
-        slice into its local per-level tables -- no collective until the
-        next sync point.
+        slice into its local tables -- no collective until the next sync
+        point -- and offers it to its pools while the device folds.  With
+        the fused kernel each slice is further padded to whole kernel
+        launches and the digits are split on the host, once for the block.
         """
         items = np.asarray(items, dtype=np.uint32)
         if items.shape[0] == 0:
@@ -192,17 +276,29 @@ class ShardedTopKService(MigratingSurface):
         if freqs is None:
             freqs = np.ones(items.shape[0], dtype=np.int64)
         freqs = np.asarray(freqs)
+        if self._kh is not None:
+            from repro.kernels.ops import check_fold_freqs
+
+            # reject kernel-unrepresentable weights BEFORE touching pools
+            # or totals, so a failed ingest leaves the service unchanged
+            with obs.span("sketch.check"):
+                check_fold_freqs(self.mode, freqs, self._kh.table.dtype)
         self.total += int(freqs.sum())
         raw_items, raw_freqs = items, freqs
-        items, freqs, per = dist.pad_block_pow2(items, freqs, self.n_shards)
+        with obs.span("sketch.fold_prep"):
+            items, freqs, per = dist.pad_block_pow2(items, freqs,
+                                                    self.n_shards)
+            args = self._fold_inputs(items, freqs, per)
+        with obs.span("sketch.fold_launch"):
+            self._local = self._fold(self._local, *args)
+        obs.count("sketch.fold_launches")
+        obs.count("sketch.fold_pad_rows",
+                  args[-1].shape[0] - raw_items.shape[0])
         for s in range(self.n_shards):
             sl = slice(s * per, (s + 1) * per)
             for j, g in enumerate(self.hspec.base.partition):
                 self._shard_pools[s][j].offer(items[sl][:, list(g)],
                                               freqs[sl])
-        params = tuple(st.params for st in self.merged.states)
-        self._local = self._fold(self._local, params, jnp.asarray(items),
-                                 jnp.asarray(freqs))
         self._dirty = True
         self._pools_dirty = True
         self._blocks_since_sync += 1
@@ -212,6 +308,26 @@ class ShardedTopKService(MigratingSurface):
         # block itself, exactly like a fresh service would -- the padded
         # copy above must NOT leak into it
         self._migration_tick(raw_items, raw_freqs)
+
+    def _fold_inputs(self, items: np.ndarray, freqs: np.ndarray, per: int):
+        """The fold's arguments after the tables, for a block padded to
+        ``per`` rows a shard."""
+        if self._kh is None:
+            return (tuple(st.params for st in self._merged.states),
+                    jnp.asarray(items), jnp.asarray(freqs))
+        from repro.kernels.ops import fold_digits
+
+        b = self._kh.block_b
+        launch = -(-per // b) * b
+        if launch != per:
+            n = self.n_shards
+            items = np.pad(items.reshape(n, per, -1),
+                           ((0, 0), (0, launch - per), (0, 0))
+                           ).reshape(n * launch, -1)
+            freqs = np.pad(freqs.reshape(n, per),
+                           ((0, 0), (0, launch - per))).reshape(-1)
+        return (self._kh.params, fold_digits(self.hspec, items),
+                freqs.astype(self._kh.table.dtype))
 
     # -- hot spec migration hooks (serving/migration.MigratingSurface) ------
 
@@ -225,7 +341,7 @@ class ShardedTopKService(MigratingSurface):
             new_spec, key, self.mesh, data_axes=self.data_axes,
             max_candidates_per_group=self.max_candidates,
             sync_every=self.sync_every, use_kernel=self.use_kernel,
-            dtype=self._dtype)
+            use_update_kernel=self.use_update_kernel, dtype=self._dtype)
 
     def _adopt(self, inc: "ShardedTopKService") -> None:
         """Adopt the successor's state wholesale; free the old tables.
@@ -236,7 +352,8 @@ class ShardedTopKService(MigratingSurface):
         local/merged tables, and pools lose their last references.
         """
         self.hspec = inc.hspec
-        self.merged = inc.merged
+        self._kh = inc._kh
+        self._merged = inc._merged
         self._local = inc._local
         self._dirty = inc._dirty
         self._pools_dirty = inc._pools_dirty
@@ -252,20 +369,23 @@ class ShardedTopKService(MigratingSurface):
     def sync(self) -> None:
         """psum-merge local deltas into the serving snapshot.
 
-        Tables: per-level all-reduce of the lazily accumulated local
-        tables, folded into ``merged`` and reset (exact by linearity).
-        The candidate-pool fold is deferred to the first query that reads
+        Tables: one program (``sharded_merge``) all-reduces the lazily
+        accumulated local tables, adds them to the merged tables (exact by
+        linearity) and returns the donated locals zeroed.  The
+        candidate-pool fold is deferred to the first query that reads
         ``candidates()`` -- the fold is pure host-side dict work with no
         collective, so paying it per sync (per block at sync_every=1)
         would burden the ingest hot path for nothing.
         """
         if not self._dirty:
             return
-        deltas = self._merge(self._local)
-        self.merged = hh.HierarchyState(states=tuple(
-            sk.SketchState(params=st.params, table=st.table + d)
-            for st, d in zip(self.merged.states, deltas)))
-        self._local = tuple(jnp.zeros_like(t) for t in self._local)
+        with obs.span("sketch.merge"):
+            tables, self._local = self._merge(self._local,
+                                              self._merged_tables())
+            self._set_merged_tables(tables)
+        obs.count("sketch.merges")
+        obs.count("sketch.merge_bytes",
+                  sum(t.size * t.dtype.itemsize for t in tables))
         self._dirty = False
         self._blocks_since_sync = 0
 
@@ -281,9 +401,9 @@ class ShardedTopKService(MigratingSurface):
 
         Exact by linearity, no drain needed: ``sync()`` psum-merges every
         survivor shard's local deltas into the replicated serving tables,
-        then the merged state is re-scattered onto the new mesh (via
-        training/fault_tolerance.elastic_remesh) with FRESH zero locals on
-        the new data axes -- merged-plus-zeros is the same sum as any
+        then the merged state is re-placed, replicated, on the new mesh
+        (the ``merged`` setter) with FRESH zero locals on the new data
+        axes -- merged-plus-zeros is the same sum as any
         other split, so queries before and after the remesh are
         bit-identical, at any N -> M.  Candidate pools fold into the new
         shard 0 (exact union under capacity, the same argument as
@@ -297,7 +417,6 @@ class ShardedTopKService(MigratingSurface):
         Refused mid-migration (the successor would need the same remesh).
         """
         from repro.launch.mesh import sketch_data_axes
-        from repro.training.fault_tolerance import elastic_remesh
 
         require_not_migrating(self._migration, "ShardedTopKService.remesh")
         self.sync()
@@ -312,12 +431,11 @@ class ShardedTopKService(MigratingSurface):
         self.mesh = new_mesh
         self.data_axes = data_axes
         self.n_shards = new_n
-        # merged tables + params are logically replicated; re-place them on
-        # the new device set so nothing still lives on a lost device
-        self.merged = elastic_remesh(self.merged, new_mesh, lambda x: dist.P())
-        self._local = dist.init_local_tables(
-            new_mesh, data_axes, new_n,
-            [st.table.shape for st in self.merged.states], self._dtype)
+        # merged tables + params are logically replicated; the setter
+        # re-places them on the new device set so nothing still lives on a
+        # lost device
+        self.merged = self.merged
+        self._local = self._zero_locals()
         self._shard_pools = (
             [folded]
             + [[SpaceSaving(self.max_candidates, len(g))
@@ -389,7 +507,7 @@ class ShardedTopKService(MigratingSurface):
             sk.SketchState(params=hh.level_params(self.hspec, base, i),
                            table=jnp.asarray(sd[f"level{i}.table"]))
             for i in range(self.hspec.n_levels)))
-        self._local = tuple(jnp.zeros_like(t) for t in self._local)
+        self._local = self._zero_locals()
         self.total = int(sd["meta.total"])
         self._dirty = False
         self._blocks_since_sync = 0
@@ -438,10 +556,12 @@ class ShardedTopKService(MigratingSurface):
         """
         self._ensure_synced()
         if self._pools_dirty:
-            self._global_pools = [
-                SpaceSaving.fold([pools[j] for pools in self._shard_pools])
-                for j in range(len(self._global_pools))
-            ]
+            with obs.span("sketch.pool_fold"):
+                self._global_pools = [
+                    SpaceSaving.fold([pools[j] for pools in self._shard_pools])
+                    for j in range(len(self._global_pools))
+                ]
+            obs.count("sketch.pool_folds")
             self._pools_dirty = False
         out = []
         for p in self._global_pools:

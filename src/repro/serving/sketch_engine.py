@@ -413,6 +413,7 @@ class SketchTopKEndpoint(MigratingSurface):
             data_axes=data_axes,
             max_candidates_per_group=self.max_candidates,
             sync_every=sync_every, use_kernel=self.use_kernel,
+            use_update_kernel=self._use_update_kernel,
             dtype=self.state.states[0].table.dtype)
         # the service's freshly drawn params are discarded: the promoted
         # state keeps this endpoint's params so existing tables stay valid.

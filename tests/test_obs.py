@@ -173,3 +173,46 @@ def test_pool_evictions_are_new_rows_less_the_pool_growth(capacity):
         assert evicted >= len(new) - (len(pool) - n0)
         if len(pool) < capacity:
             assert evicted == 0
+
+
+def test_sharded_fold_merge_and_pool_fold_counts():
+    """On the sharded service with the fused fold: one fold launch a block
+    with its pad rows, one merge per ``shard_sync_every`` blocks carrying
+    the whole stack, and one pool fold per flush that follows ingest."""
+    from repro.core import hierarchy as hh
+    from repro.kernels.hier_update import make_hier_plan
+    from repro.launch.mesh import make_mesh
+    from repro.serving.sharded_topk import ShardedTopKService
+
+    stream = _stream()
+    spec = sk.mod_sketch_spec(stream.schema, [(0,), (1,)], (16, 64), 4)
+    svc = ShardedTopKService(spec, KEY, make_mesh((1,), ("data",)),
+                             use_update_kernel=True, sync_every=None)
+    eng = SketchServeEngine(svc, max_staleness=0, shard_sync_every=2)
+    blocks = _blocks(stream, -(-stream.items.shape[0] // 3))
+    assert len(blocks) == 3
+    before = obs.counters()
+    for items, freqs in blocks:
+        eng.ingest(items, freqs)
+    got = _delta(before)
+    assert got["sketch.fold_launches"] == len(blocks)
+    assert got["sketch.fold_pad_rows"] == sum(1024 - len(f) for _, f in blocks)
+    merges = len(blocks) // 2
+    stack = 4 * make_hier_plan(hh.HierarchySpec.from_spec(spec)).padded_cols
+    assert got["sketch.merges"] == merges
+    assert got["sketch.merge_bytes"] == merges * stack * 4
+    assert "sketch.pool_folds" not in got
+
+    before = obs.counters()
+    eng.submit_topk(3)
+    eng.submit_heavy_hitters(50)
+    eng.flush()
+    got = _delta(before)
+    assert got["sketch.merges"] == 1          # the odd block left unmerged
+    assert got["sketch.pool_folds"] == 1
+    assert got["sketch.snapshots"] == 1
+    before = obs.counters()
+    eng.submit_topk(3)
+    eng.flush()                               # nothing new: no merge, no fold
+    got = _delta(before)
+    assert "sketch.merges" not in got and "sketch.pool_folds" not in got

@@ -225,6 +225,199 @@ def test_endpoint_to_sharded_continuation():
 
 
 # --------------------------------------------------------------------------
+# The fused-kernel fold (use_update_kernel=True): one subprocess computes
+# every case, one parametrised test checks each
+# --------------------------------------------------------------------------
+
+_KERNEL_CASES = [(n, pad) for n in (1, 2, 4) for pad in (False, True)]
+
+
+@pytest.fixture(scope="module")
+def kernel_fold_cases():
+    """Per (shards, pad rows): the kernel-mode service against the per-level
+    reference fold and the scatter-add service, same key and blocks.
+    Whole blocks are ``shards x 1024`` rows (one launch a shard, no pad
+    rows); padded blocks are 700 and 1,500 rows."""
+    import json
+
+    out = _run(f"""
+        import json, jax, numpy as np
+        from repro import obs
+        from repro.launch.mesh import make_mesh
+        from repro.core import sketch as sk, hierarchy as hh
+        from repro.core.hashing import KeySchema
+        from repro.serving.sharded_topk import ShardedTopKService
+
+        rng = np.random.default_rng(11)
+        schema = KeySchema(domains=(1 << 20, 1 << 12))
+        spec = sk.mod_sketch_spec(schema, [(0,), (1,)], (64, 64), 3)
+        hspec = hh.HierarchySpec.from_spec(spec)
+        key = jax.random.PRNGKey(3)
+        hot = rng.integers(0, 1 << 12, size=(8, 2))
+        res = {{}}
+        for n, pad in {_KERNEL_CASES!r}:
+            sizes = [700, 1500] if pad else [n * 1024, n * 1024]
+            items = rng.integers(0, 1 << 12, size=(sum(sizes), 2))
+            items[::7] = hot[rng.integers(0, 8, size=len(items[::7]))]
+            items = items.astype(np.uint32)
+            freqs = rng.integers(1, 40, size=len(items)).astype(np.int64)
+            mesh = make_mesh((n,), ("data",))
+            krn = ShardedTopKService(spec, key, mesh, use_update_kernel=True,
+                                     sync_every=None)
+            sca = ShardedTopKService(spec, key, mesh, sync_every=None)
+            before = obs.counters()
+            edges = np.cumsum([0] + sizes)
+            for s, e in zip(edges[:-1], edges[1:]):
+                krn.ingest(items[s:e], freqs[s:e])
+            got = {{k: v - before.get(k, 0) for k, v in obs.counters().items()}}
+            for s, e in zip(edges[:-1], edges[1:]):
+                sca.ingest(items[s:e], freqs[s:e])
+            ref = hh.update_reference(hspec, hh.init_hierarchy(hspec, key),
+                                      items, freqs)
+            tk = [np.asarray(st.table) for st in krn.state().states]
+            ts = [np.asarray(st.table) for st in sca.state().states]
+            tr = [np.asarray(st.table) for st in ref.states]
+            thr = int(freqs.sum()) // 100
+            hk, hs = krn.heavy_hitters(thr), sca.heavy_hitters(thr)
+            kk, ks = krn.topk(5, min_threshold=1), sca.topk(5, min_threshold=1)
+            same = lambda a, b: all(np.array_equal(x, y) for x, y in zip(a, b))
+            # a shard's slice: the power of two at or above z / n rows,
+            # then whole 1,024-row launches
+            per = lambda z: 1 << max(-(-z // n) - 1, 0).bit_length()
+            res[f"{{n}}-{{pad}}"] = dict(
+                ref=all(np.array_equal(a, b) for a, b in zip(tk, tr)),
+                scatter=all(np.array_equal(a, b) for a, b in zip(tk, ts)),
+                heavy=same(hk, hs) and len(hk[0]) > 0,
+                topk=same(kk, ks) and len(kk[0]) == 5,
+                replicas=len(krn.state().states[-1].table.sharding.device_set),
+                launches=got.get("sketch.fold_launches", 0),
+                pad_rows=got.get("sketch.fold_pad_rows", 0),
+                want_pad=sum(n * -(-per(z) // 1024) * 1024 - z
+                             for z in sizes))
+        print("RESULT", json.dumps(res))
+    """, devices=4)
+    line = [x for x in out.splitlines() if x.startswith("RESULT ")][-1]
+    return json.loads(line[len("RESULT "):])
+
+
+@pytest.mark.parametrize("shards,pad", _KERNEL_CASES,
+                         ids=[f"{n}shards-{'pad' if p else 'whole'}"
+                              for n, p in _KERNEL_CASES])
+def test_kernel_fold_matches_reference_and_scatter_add(kernel_fold_cases,
+                                                       shards, pad):
+    """Tables, heavy hitters and top-k of the fused-kernel service are
+    bit-identical to the per-level reference fold and to the scatter-add
+    service; one fold launch per block, pad rows counted."""
+    got = kernel_fold_cases[f"{shards}-{pad}"]
+    assert got["ref"] and got["scatter"], got
+    assert got["heavy"] and got["topk"], got
+    assert got["replicas"] == shards
+    assert got["launches"] == 2
+    assert got["pad_rows"] == got["want_pad"]
+    assert (got["pad_rows"] > 0) == pad
+
+
+def test_kernel_mode_round_trips_and_spans():
+    """Kernel mode keeps every round trip of the scatter-add mode: a
+    state_dict restored across shard counts and across modes, a 4 -> 2
+    remesh, a migration, ``to_sharded`` from a kernel endpoint; and each
+    shard offers its slice to its pools once a block (per group)."""
+    print(_run("""
+        import glob, tempfile, jax, numpy as np
+        from jax.profiler import ProfileData
+        from repro.launch.mesh import make_mesh
+        from repro.core import distributed as dist, sketch as sk, hierarchy as hh
+        from repro.serving.engine import SketchTopKEndpoint
+        from repro.serving.sharded_topk import ShardedTopKService
+        from repro.streams import zipf_hh_workload
+
+        wl = zipf_hh_workload(n_occurrences=20_000, n_edges=3_000, seed=4)
+        spec = sk.mod_sketch_spec(wl.stream.schema, [(0,), (1,)], (64, 64), 3)
+        key = jax.random.PRNGKey(6)
+        items, freqs = wl.stream.items, wl.stream.freqs
+        half = len(items) // 2
+        m4, m2 = make_mesh((4,), ("data",)), make_mesh((2,), ("data",))
+
+        def tables(svc):
+            return [np.asarray(st.table) for st in svc.state().states]
+
+        def same(a, b):
+            return all(np.array_equal(x, y) for x, y in zip(a, b))
+
+        whole = ShardedTopKService(spec, key, m4)
+        whole.ingest(items, freqs)
+        want, want_tk = tables(whole), whole.topk(10)
+
+        def replicated(x, mesh):
+            return (x.sharding.is_fully_replicated
+                    and x.sharding.device_set == set(mesh.devices.flat))
+
+        trace = tempfile.mkdtemp()
+        svc = ShardedTopKService(spec, key, m4, use_update_kernel=True)
+        # params and merged stack placed on the mesh once, at init; the
+        # locals' zeros program compiled once per mesh, shapes and dtype
+        assert replicated(svc._kh.params.q, m4)
+        assert replicated(svc._kh.table, m4)
+        assert replicated(whole.merged.states[0].params.q, m4)
+        hits = dist._zeros_program.cache_info().hits
+        ShardedTopKService(spec, key, m4, use_update_kernel=True)
+        assert dist._zeros_program.cache_info().hits == hits + 1
+        with jax.profiler.trace(trace):
+            svc.ingest(items[:half], freqs[:half])
+        (path,) = glob.glob(f"{trace}/plugins/profile/*/*.xplane.pb")
+        names = [e.name for p in ProfileData.from_file(path).planes
+                 if p.name.startswith("/host:") for ln in p.lines
+                 for e in ln.events]
+        # one offer per shard and group: 4 shards x 2 groups
+        assert names.count("sketch.pool_offer") == 4 * 2, names.count(
+            "sketch.pool_offer")
+        for n in ("sketch.fold_prep", "sketch.fold_launch", "sketch.merge"):
+            assert names.count(n) == 1, n
+        sd = svc.state_dict()
+
+        for mesh, kernel in ((m2, True), (m4, True), (m2, False)):
+            back = ShardedTopKService(spec, key, mesh,
+                                      use_update_kernel=kernel)
+            back.load_state_dict(sd)
+            if kernel:
+                assert replicated(back._kh.params.q, mesh)
+            back.ingest(items[half:], freqs[half:])
+            assert same(tables(back), want), (mesh.shape, kernel)
+            assert same(back.topk(10), want_tk)
+
+        svc.remesh(m2)
+        assert svc.state().states[-1].table.sharding.mesh.shape["data"] == 2
+        assert replicated(svc._kh.params.q, m2)
+        svc.ingest(items[half:], freqs[half:])
+        assert same(tables(svc), want) and same(svc.topk(10), want_tk)
+
+        ep = SketchTopKEndpoint(spec, key, use_update_kernel=True)
+        ep.ingest(items[:half], freqs[:half])
+        promoted = ep.to_sharded(m4)
+        assert promoted.use_update_kernel
+        promoted.ingest(items[half:], freqs[half:])
+        assert same(tables(promoted), want)
+
+        spec_new = sk.mod_sketch_spec(wl.stream.schema, [(0,), (1,)],
+                                      (16, 64), 3)
+        mig_key = jax.random.fold_in(key, 1)
+        cut = 2 * half // 3
+        fresh = ShardedTopKService(spec_new, mig_key, m4)
+        fresh.ingest(items[cut:], freqs[cut:])
+        mig = ShardedTopKService(spec, key, m4, use_update_kernel=True)
+        mig.ingest(items[:cut], freqs[:cut])
+        mig.begin_migration(spec_new, mig_key,
+                            warmup=int(freqs[cut:half].sum()))
+        mig.ingest(items[cut:half], freqs[cut:half])
+        assert not mig.migrating and mig.use_update_kernel
+        mig.ingest(items[half:], freqs[half:])
+        assert same(tables(mig), tables(fresh))
+        assert same(mig.topk(10), fresh.topk(10))
+        print("kernel-mode round trips OK")
+    """, devices=4))
+
+
+# --------------------------------------------------------------------------
 # Property tests (single device, tier-1): merge algebra + shard merges
 # --------------------------------------------------------------------------
 

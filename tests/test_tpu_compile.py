@@ -6,7 +6,10 @@ limits are only checked by the TPU compiler.  These tests describe a v5e
 topology (the TPU compiler ships with jaxlib) and run ``.lower().compile()``
 on each kernel with ``interpret=False`` at the widths of ``chip_smoke.py``:
 w = 4 rows, a 2^20-cell finest level, two IPv4 modules (4 chunk columns),
-1024-row ingest blocks and the descent's 2^16 lanes per launch.
+1024-row ingest blocks and the descent's 2^16 lanes per launch.  The
+sharded service's fold and merge programs are compiled for the four chips
+of a v5e 2x2 host at the deployment widths of the ``ipv4-zipf-cm-dp4``
+benchmark configuration (one 2^20-cell level, 1,024 rows a chip).
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and the fixture lets
@@ -18,8 +21,9 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from repro.core import distributed as dist
 from repro.core import hierarchy as hh
 from repro.core import sketch as sk
 from repro.core.hashing import KeySchema
@@ -54,7 +58,7 @@ C = SCHEMA.total_chunks
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
@@ -68,8 +72,18 @@ def one_chip():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(topo.devices, ("data",))
 
 
 def _compile(fn, *shapes, sharding):
@@ -162,3 +176,35 @@ def test_conservative_kernel_refuses_compiled_launch(one_chip):
         _compile(fn, ((W, 1024), jnp.int32), ((BLOCK, C), jnp.uint32),
                  ((BLOCK,), jnp.int32), ((W, C), jnp.uint32),
                  ((W, 2), jnp.uint32), sharding=one_chip)
+
+
+def test_sharded_fold_and_merge_compile_on_four_chips(four_chips):
+    """ShardedTopKService(use_update_kernel=True)'s two programs: the fold
+    runs the fused kernel on each chip's slice, the merge all-reduces."""
+    base = sk.mod_sketch_spec(SCHEMA, [(0, 1)], (1 << 20,), W)
+    hplan = make_hier_plan(hh.HierarchySpec.from_spec(base))
+    axes = ("data",)
+    data = NamedSharding(four_chips, PartitionSpec(axes))
+    rep = NamedSharding(four_chips, PartitionSpec())
+    local = ((4, W, hplan.padded_cols), jnp.int32)
+
+    def shape(s, d, sharding):
+        return jax.ShapeDtypeStruct(s, d, sharding=sharding)
+
+    def sharded_fold(loc, q, r, ch, f):
+        return dist.lazy_hierarchy_update_fused(
+            hplan, four_chips, axes, loc, q, r, ch, f, block_b=BLOCK,
+            interpret=False)
+
+    def sharded_merge(loc, tables):
+        return dist.sync_local_hierarchy(four_chips, axes, loc, tables)
+
+    fold = jax.jit(sharded_fold, donate_argnums=(0,)).lower(
+        (shape(*local, data),), shape((W, C), jnp.uint32, rep),
+        shape((W, 1), jnp.uint32, rep), shape((4 * BLOCK, C), jnp.uint32, data),
+        shape((4 * BLOCK,), jnp.int32, data)).compile()
+    assert "tpu_custom_call" in fold.as_text()
+    merge = jax.jit(sharded_merge, donate_argnums=(0,)).lower(
+        (shape(*local, data),),
+        (shape((W, hplan.padded_cols), jnp.int32, rep),)).compile()
+    assert "all-reduce" in merge.as_text()
