@@ -1,31 +1,43 @@
 """Space-saving (Misra-Gries style) summaries of weighted value streams.
 
-Used by the serving endpoint's candidate-pool admission
-(serving/engine.py): each partition group keeps one bounded summary of the
-group values seen so far, so late-arriving heavy values still enter the
-candidate sets by evicting the lightest entry instead of being dropped by a
-first-come cap.
+The serving surfaces' candidate pools (serving/sketch_engine.py,
+serving/sharded_topk.py, serving/windowed_topk.py): each partition group
+keeps one bounded summary of the group values seen so far, so late-arriving
+heavy values still enter the candidate sets by evicting the lightest
+entries instead of being dropped by a first-come cap.
 
-Standard weighted space-saving (Metwally et al. 2005): at capacity, an
-unseen value replaces the minimum-count entry and inherits its count (the
-``err`` field records that inherited overestimate).  Guarantees, with
-capacity m over total weight W:
+A summary is three arrays sorted by row: ``_keys`` (one key per row that
+orders as the rows do lexicographically: the row packed into a uint64 when
+it fits in 64 bits, its big-endian bytes otherwise), ``_counts`` and
+``_errs`` (float64).  One routine, :meth:`SpaceSaving._merge`, unions
+another sorted summary in by the mergeable-summaries rule (Agarwal et al.,
+PODS 2012) and truncates back to capacity, dropping the lowest entries by
+``(count, key)``.  :meth:`offer` merges in the block's exact summary (an
+exact summary has floor 0); :meth:`merge_from` and :meth:`fold` merge
+other pools.
 
-  * count(v) >= true(v)            (counts only overestimate),
-  * count(v) - true(v) <= W / m    (the inherited error is bounded),
-  * every value with true(v) > W / m is in the summary.
+Block rule guarantees, capacity m over total weight W, ``floor`` the
+pool's minimum count when full (0 when not):
+
+  * count(v) >= true(v): a row new to the pool takes ``floor + f``, and a
+    row absent from a full pool has true(v) <= floor (it was dropped at a
+    count <= the floor, and the floor never falls: every union entry is
+    >= it).
+  * count(v) - true(v) <= W / m: the inherited error is the floor at
+    admission; every union entry is >= the floor, so the d dropped entries
+    sum to >= d * floor, the m kept ones to <= W, and the floor <= W / m.
+  * every value with true(v) > W / m is in the summary: a dropped row has
+    true(v) <= count(v) <= the new floor <= W / m.
 
 Counts are float64 so fractional weights (f32 gradient streams) admit
 normally; float64 sums of integer weights stay exact below 2^53.  Only the
 *values* feed the heavy-hitter descent (estimates come from the sketch
 tables, not from these counts), so the counts' job is eviction ranking and
-the W/m admission guarantee.  Eviction uses a lazy min-heap (stale entries
-skipped on pop), so a block of d distinct rows costs O(d log m), not
-O(d * m).
+the W/m admission guarantee.  A block of d distinct rows costs one sort of
+the block, O(d log m) searches and O(m) array copies: no per-row Python.
 """
 from __future__ import annotations
 
-import heapq
 from typing import Dict, List, Tuple
 
 import numpy as np
@@ -41,14 +53,77 @@ class SpaceSaving:
     def __init__(self, capacity: int, n_cols: int):
         if capacity < 1:
             raise ValueError("capacity >= 1 required")
+        if n_cols < 1:
+            raise ValueError("n_cols >= 1 required")
         self.capacity = int(capacity)
         self.n_cols = int(n_cols)
-        self._count: Dict[Row, float] = {}
-        self._err: Dict[Row, float] = {}
-        self._heap: List[Tuple[float, Row]] = []   # lazy: may hold stale counts
+        self._keys = self._encode(np.zeros((0, self.n_cols), np.uint32))
+        self._counts = np.zeros(0, np.float64)
+        self._errs = np.zeros(0, np.float64)
 
     def __len__(self) -> int:
-        return len(self._count)
+        return self._keys.shape[0]
+
+    # -- keys: one per row, ordered as the rows are lexicographically --------
+
+    def _encode(self, rows: np.ndarray) -> np.ndarray:
+        if self.n_cols == 1:
+            return rows[:, 0].astype(np.uint64)
+        if self.n_cols == 2:
+            return (rows[:, 0].astype(np.uint64) << np.uint64(32)) | rows[:, 1]
+        return (np.ascontiguousarray(rows, dtype=">u4")
+                .view(f"S{4 * self.n_cols}").ravel())
+
+    def _decode(self, keys: np.ndarray) -> np.ndarray:
+        if self.n_cols == 1:
+            return keys.astype(np.uint32)[:, None]
+        if self.n_cols == 2:
+            return np.stack([(keys >> np.uint64(32)).astype(np.uint32),
+                             keys.astype(np.uint32)], axis=1)
+        return keys.view(">u4").reshape(-1, self.n_cols).astype(np.uint32)
+
+    # -- the one merge routine ------------------------------------------------
+
+    def _floor(self) -> float:
+        """The most an absent row can weigh: the min count when full, else 0
+        (absent from a summary under capacity means truly unseen)."""
+        return float(self._counts.min()) if len(self) >= self.capacity else 0.0
+
+    def _merge(self, keys: np.ndarray, counts: np.ndarray, errs: np.ndarray,
+               floor: float) -> Tuple[int, int]:
+        """Union a summary given as sorted distinct ``keys`` with their
+        ``counts``/``errs`` into this one; rows absent from it weigh its
+        ``floor``.  Returns (rows already resident, rows dropped)."""
+        own = self._floor()
+        pos = np.searchsorted(self._keys, keys)
+        hit = pos < len(self)
+        hit[hit] = self._keys[pos[hit]] == keys[hit]
+        i = pos[hit]
+        c, e = self._counts + floor, self._errs + floor
+        c[i] = self._counts[i] + counts[hit]
+        e[i] = self._errs[i] + errs[hit]
+        new = ~hit
+        at = pos[new]
+        self._keys = np.insert(self._keys, at, keys[new])
+        self._counts = np.insert(c, at, counts[new] + own)
+        self._errs = np.insert(e, at, errs[new] + own)
+        extra = len(self) - self.capacity
+        if extra > 0:
+            self._drop_lowest(extra)
+        return int(np.count_nonzero(hit)), max(extra, 0)
+
+    def _drop_lowest(self, extra: int) -> None:
+        """Drop the ``extra`` entries lowest by ``(count, key)``: every count
+        below the extra-th smallest, then its ties in key (array) order."""
+        c = self._counts
+        t = np.partition(c, extra - 1)[extra - 1]
+        drop = c < t
+        drop[np.flatnonzero(c == t)[: extra - np.count_nonzero(drop)]] = True
+        keep = ~drop
+        self._keys, self._counts, self._errs = (
+            self._keys[keep], c[keep], self._errs[keep])
+
+    # -- ingest ---------------------------------------------------------------
 
     def offer(self, values: np.ndarray, freqs: np.ndarray | None = None) -> None:
         """Fold a block of value rows with weights into the summary."""
@@ -61,92 +136,50 @@ class SpaceSaving:
             freqs = np.ones(values.shape[0], dtype=np.int64)
         freqs = np.asarray(freqs, dtype=np.float64)
         with obs.span("sketch.pool_offer", rows=values.shape[0]):
-            # aggregate the block first: one summary op per *distinct* row
-            uniq, inv = np.unique(values, axis=0, return_inverse=True)
-            tot = np.bincount(inv.reshape(-1), weights=freqs)
-            evicted = 0
-            for row, f in zip(uniq.tolist(), tot.tolist()):
-                if f <= 0:
-                    continue  # zero-weight pad rows are not observations
-                evicted += self._insert(tuple(row), float(f))
-        obs.count("sketch.pool_offered", uniq.shape[0])
+            # the block's exact summary: one sort and a segmented sum
+            keys, inv = np.unique(self._encode(values), return_inverse=True)
+            tot = np.bincount(inv.reshape(-1), weights=freqs,
+                              minlength=keys.shape[0])
+            live = tot > 0  # zero-weight pad rows are not observations
+            hits, evicted = self._merge(keys[live], tot[live],
+                                        np.zeros(np.count_nonzero(live)), 0.0)
+        obs.count("sketch.pool_offered", keys.shape[0])
+        obs.count("sketch.pool_hits", hits)
         obs.count("sketch.pool_evicted", evicted)
 
-    def _pop_min(self) -> Tuple[float, Row]:
-        """Pop the live minimum-count entry, discarding stale heap entries."""
-        while True:
-            c, row = heapq.heappop(self._heap)
-            if self._count.get(row) == c:
-                return c, row
-
-    def _insert(self, row: Row, f: float) -> bool:
-        """Add ``f`` to ``row``; True when that evicted another row."""
-        evicted = False
-        if row in self._count:
-            self._count[row] += f
-        elif len(self._count) < self.capacity:
-            self._count[row] = f
-            self._err[row] = 0.0
-        else:
-            floor, victim = self._pop_min()
-            del self._count[victim]
-            del self._err[victim]
-            self._count[row] = floor + f
-            self._err[row] = floor
-            evicted = True
-        heapq.heappush(self._heap, (self._count[row], row))
-        if len(self._heap) > 4 * self.capacity:
-            self._compact_heap()
-        return evicted
-
-    def _compact_heap(self) -> None:
-        """Drop stale entries (bounds the heap at O(capacity) regardless of
-        how many increments long-lived hot rows accumulate)."""
-        self._heap = [(c, r) for r, c in self._count.items()]
-        heapq.heapify(self._heap)
+    # -- readers --------------------------------------------------------------
 
     def values(self) -> np.ndarray:
-        """All summarized rows: uint32[K, n_cols], sorted lexicographically.
+        """All summarized rows: uint32[K, n_cols], sorted lexicographically
+        and distinct.
 
         The canonical order makes descent answers (and their tie order)
         independent of admission order, so surfaces that hold the same
         rows -- one pool, or shard pools folded into one -- answer
         identically."""
-        if not self._count:
-            return np.zeros((0, self.n_cols), dtype=np.uint32)
-        rows = np.asarray(list(self._count), dtype=np.uint32)
-        return rows[np.lexsort(rows.T[::-1])]
+        return self._decode(self._keys)
 
     def counts(self) -> Dict[Row, float]:
-        return dict(self._count)
+        return dict(zip(map(tuple, self.values().tolist()),
+                        self._counts.tolist()))
 
     # -- durable state (serving/recovery.py snapshot currency) --------------
 
     def state_dict(self) -> Dict[str, np.ndarray]:
-        """The summary as three flat arrays: rows / counts / errs.
+        """The summary as its three arrays: rows (sorted, as
+        :meth:`values`) / counts / errs.
 
-        Row order is the dict's insertion order, which matters: the plain
-        endpoint feeds ``values()`` to the descent unsorted, so a restore
-        that permuted rows could permute top-k tie order.  ``load_state``
-        re-inserts in the same order, making the round trip bit-exact --
-        including all later evictions, which depend only on dict contents
-        and order."""
-        rows = self.values()
-        return {
-            "rows": rows,
-            "counts": np.asarray([self._count[tuple(r)] for r in rows.tolist()],
-                                 dtype=np.float64),
-            "errs": np.asarray([self._err[tuple(r)] for r in rows.tolist()],
-                               dtype=np.float64),
-        }
+        Every later offer depends only on these arrays, so the round trip
+        through :meth:`load_state` is bit-exact, later evictions included."""
+        return {"rows": self.values(), "counts": self._counts.copy(),
+                "errs": self._errs.copy()}
 
     def load_state(self, rows: np.ndarray, counts: np.ndarray,
                    errs: np.ndarray) -> None:
         """Restore a summary saved by :meth:`state_dict` (same capacity/width).
 
-        Overwrites the current contents wholesale; the rebuilt heap is the
-        compacted form of the loaded counts, so eviction behaviour after a
-        restore is identical to the uninterrupted summary's."""
+        Overwrites the current contents wholesale.  Rows are put in key
+        order (a no-op on a saved summary) and must be distinct."""
         rows = np.asarray(rows, dtype=np.uint32)
         if rows.ndim != 2 or rows.shape[1] != self.n_cols:
             raise ValueError(f"rows must be [K, {self.n_cols}]")
@@ -154,13 +187,16 @@ class SpaceSaving:
             raise ValueError(
                 f"loaded summary has {rows.shape[0]} rows but capacity is "
                 f"{self.capacity}: capacity must match the saved summary")
-        counts = np.asarray(counts, dtype=np.float64)
-        errs = np.asarray(errs, dtype=np.float64)
-        self._count = {tuple(r): float(c)
-                       for r, c in zip(rows.tolist(), counts.tolist())}
-        self._err = {tuple(r): float(e)
-                     for r, e in zip(rows.tolist(), errs.tolist())}
-        self._compact_heap()
+        keys = self._encode(rows)
+        order = np.argsort(keys, kind="stable")
+        keys = keys[order]
+        if np.any(keys[1:] == keys[:-1]):
+            raise ValueError("loaded summary repeats a row")
+        self._keys = keys
+        self._counts = np.asarray(counts, dtype=np.float64)[order]
+        self._errs = np.asarray(errs, dtype=np.float64)[order]
+
+    # -- cross-shard merge ----------------------------------------------------
 
     @classmethod
     def fold(cls, summaries: List["SpaceSaving"]) -> "SpaceSaving":
@@ -173,7 +209,7 @@ class SpaceSaving:
         <= W_i / m).  When every shard is under capacity the fold is exact
         -- counts are plain sums and no row is lost -- which is what makes
         the sharded serving candidate pools shard-count invariant below
-        capacity (serving/sharded_topk.py)."""
+        capacity (serving/sharded_topk.py); ``fold([p])`` is a copy of p."""
         summaries = list(summaries)
         if not summaries:
             raise ValueError("fold requires at least one summary")
@@ -186,32 +222,15 @@ class SpaceSaving:
         """Fold another summary in (cross-shard candidate merge).
 
         Mergeable-summaries rule (Agarwal et al. 2012): a row absent from
-        one side contributes that side's min count when the side is at
-        capacity (its worst-case possible count there -- the row may have
-        been evicted with up to that much mass) and 0 when the side is
-        under capacity (absent then means truly unseen).  The union is
-        truncated back to capacity keeping the largest counts.  This
-        preserves count(v) >= true(v) for every retained row, so a value
-        heavy on either shard still out-ranks light entries in the merged
-        summary; the error bound grows to the sum of the two floors.
+        one side contributes that side's floor -- its min count when the
+        side is at capacity (the row may have been evicted with up to that
+        much mass), 0 when under capacity (absent then means truly unseen).
+        The union is truncated back to capacity, dropping the lowest by
+        ``(count, key)``.  This preserves count(v) >= true(v) for every
+        retained row, so a value heavy on either shard still out-ranks
+        light entries in the merged summary; the error bound grows to the
+        sum of the two floors.
         """
         if other.n_cols != self.n_cols:
             raise ValueError("cannot merge summaries of different widths")
-        m_self = (min(self._count.values())
-                  if len(self._count) >= self.capacity else 0.0)
-        m_other = (min(other._count.values())
-                   if len(other._count) >= other.capacity else 0.0)
-        count, err = {}, {}
-        for row in set(self._count) | set(other._count):
-            cs, co = self._count.get(row), other._count.get(row)
-            count[row] = ((cs if cs is not None else m_self)
-                          + (co if co is not None else m_other))
-            err[row] = ((self._err[row] if cs is not None else m_self)
-                        + (other._err[row] if co is not None else m_other))
-        if len(count) > self.capacity:
-            keep = sorted(count, key=count.__getitem__,
-                          reverse=True)[: self.capacity]
-            count = {r: count[r] for r in keep}
-            err = {r: err[r] for r in keep}
-        self._count, self._err = count, err
-        self._compact_heap()
+        self._merge(other._keys, other._counts, other._errs, other._floor())

@@ -546,10 +546,10 @@ class ShardedTopKService(MigratingSurface):
     def candidates(self) -> List[np.ndarray]:
         """Per-group candidate arrays from the folded global pools.
 
-        Rows are sorted lexicographically (np.unique) so the descent --
-        and hence top-k tie order -- never depends on the dict iteration
-        order of the folded pools, which varies with shard count.  The
-        global pools are (re-)folded here from the cumulative shard pools
+        Rows come sorted lexicographically and distinct from
+        ``SpaceSaving.values``, so the descent -- and hence top-k tie
+        order -- never depends on the shard count.  The global pools
+        are (re-)folded here from the cumulative shard pools
         with the mergeable-summaries rule when ingest has run since the
         last fold; recomputing from scratch avoids compounding fold floors
         fold over fold.
@@ -563,11 +563,7 @@ class ShardedTopKService(MigratingSurface):
                 ]
             obs.count("sketch.pool_folds")
             self._pools_dirty = False
-        out = []
-        for p in self._global_pools:
-            vals = p.values()
-            out.append(np.unique(vals, axis=0) if len(vals) else vals)
-        return out
+        return [p.values() for p in self._global_pools]
 
     def heavy_hitters(self, threshold: int,
                       candidates: Optional[List[np.ndarray]] = None,

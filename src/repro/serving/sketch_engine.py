@@ -280,7 +280,7 @@ class SketchTopKEndpoint(MigratingSurface):
         Covers everything a bit-exact restore needs: every level table,
         the shared hash params (the finest level's arrays -- every
         coarser level's params are prefix slices of them), the stream
-        total, and each group's space-saving pool in insertion order.
+        total, and each group's space-saving pool as its sorted arrays.
         A config fingerprint guards against restoring into an endpoint
         built on a different spec/mode/dtype.
 

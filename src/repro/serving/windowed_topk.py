@@ -186,15 +186,12 @@ class WindowedTopKService:
         Expired epochs' pools are gone, so a key seen only outside the
         window cannot re-enter the descent; a key inside the window sits in
         some live pool (under capacity: surely; at capacity: iff it carries
-        > W_epoch/m of its epoch's weight).  Rows sorted lexicographically
-        so descent order never depends on pool/dict iteration order."""
+        > W_epoch/m of its epoch's weight).  Rows come sorted
+        lexicographically from ``SpaceSaving.values`` so descent order
+        never depends on the order of the live pools."""
         live = win.live_slots(self.wspec, self.wstate)
-        out = []
-        for j in range(len(self.wspec.base.partition)):
-            folded = SpaceSaving.fold([self._pools[s][j] for s in live])
-            vals = folded.values()
-            out.append(np.unique(vals, axis=0) if len(vals) else vals)
-        return out
+        return [SpaceSaving.fold([self._pools[s][j] for s in live]).values()
+                for j in range(len(self.wspec.base.partition))]
 
     # -- durable state (serving/recovery.py snapshot currency) ---------------
 

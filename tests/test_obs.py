@@ -130,31 +130,66 @@ def test_descent_rounds_are_the_thresholds_the_serial_descent_tries():
     assert got["sketch.grid_launches"] >= 1
 
 
+def _block_rule(count, err, capacity, values, freqs):
+    """Plain dict reference of the pool's block rule: the block's exact
+    summary unioned in, then the lowest by (count, key) dropped.  Returns
+    (rows already resident, rows dropped)."""
+    block = {}
+    for row, f in zip(map(tuple, values.tolist()), freqs.tolist()):
+        block[row] = block.get(row, 0.0) + f
+    floor = min(count.values()) if len(count) >= capacity else 0.0
+    hits = 0
+    for row, f in block.items():
+        if f <= 0:
+            continue
+        hits += row in count
+        if row in count:
+            count[row] += f
+        else:
+            count[row], err[row] = floor + f, floor
+    extra = max(0, len(count) - capacity)
+    for row in sorted(count, key=lambda r: (count[r], r))[:extra]:
+        del count[row], err[row]
+    return hits, extra
+
+
 def test_pool_evictions_match_a_pool_driven_row_by_row():
     stream = _stream(seed=7)
     capacity = 24
-    pool, brute = SpaceSaving(capacity, 1), SpaceSaving(capacity, 1)
-    want = got = offered = 0
+    pool = SpaceSaving(capacity, 1)
+    count, err = {}, {}
+    want = got = hits = want_hits = offered = 0
     for items, freqs in _blocks(stream, 150):
         values = items[:, :1]
         before = obs.counters()
         pool.offer(values, freqs)
         d = _delta(before)
         got += d.get("sketch.pool_evicted", 0)
+        hits += d.get("sketch.pool_hits", 0)
         offered += d["sketch.pool_offered"]
-        # the same distinct rows and weights, one offer each, in the
-        # order the block's offer takes them
-        uniq, inv = np.unique(values, axis=0, return_inverse=True)
-        tot = np.bincount(inv.reshape(-1), weights=freqs)
-        for row, f in zip(uniq, tot):
-            if f > 0:
-                want += (len(brute) == capacity
-                         and tuple(row.tolist()) not in brute.counts())
-                brute.offer(row[None], np.asarray([f]))
-    assert want > 0
+        h, e = _block_rule(count, err, capacity, values, freqs)
+        want_hits += h
+        want += e
+    assert want > 0 and want_hits > 0
     assert got == want
-    assert pool.counts() == brute.counts()
+    assert hits == want_hits
+    assert pool.counts() == count
+    sd = pool.state_dict()
+    assert dict(zip(map(tuple, sd["rows"].tolist()), sd["errs"].tolist())) == err
     assert offered >= len(pool)
+
+
+def test_pool_hits_count_the_blocks_resident_rows():
+    pool = SpaceSaving(8, 1)
+    pool.offer(np.asarray([[1], [2], [3]], np.uint32))
+    before = obs.counters()
+    pool.offer(np.asarray([[1], [1], [4], [2], [5]], np.uint32),
+               np.asarray([1, 2, 1, 1, 0]))
+    got = _delta(before)
+    assert got["sketch.pool_offered"] == 4      # distinct rows, pad included
+    assert got["sketch.pool_hits"] == 2         # 1 and 2 were resident
+    assert "sketch.pool_evicted" not in got     # under capacity
+    assert pool.counts() == {(1,): 4, (2,): 2, (3,): 1, (4,): 1}
 
 
 @pytest.mark.parametrize("capacity", [8, 1 << 10])
@@ -169,8 +204,7 @@ def test_pool_evictions_are_new_rows_less_the_pool_growth(capacity):
         new = {tuple(r) for r in items.tolist()} - seen
         evicted = _delta(before).get("sketch.pool_evicted", 0)
         # rows new to the pool either filled free room or evicted another
-        # (a row evicted and re-admitted within the block counts twice)
-        assert evicted >= len(new) - (len(pool) - n0)
+        assert evicted == len(new) - (len(pool) - n0)
         if len(pool) < capacity:
             assert evicted == 0
 

@@ -168,24 +168,111 @@ def test_fold_validation():
 
 
 def test_lazy_heap_stays_bounded():
-    """Regression: repeated increments of resident rows pushed one stale
-    heap entry each and nothing ever drained them under capacity."""
+    """Regression: repeated increments of resident rows once grew the lazy
+    heap without bound.  Storage is now the resident rows alone, and
+    eviction still finds the minimum."""
     s = SpaceSaving(capacity=8, n_cols=1)
     hot = _rows(1, 2, 3)
     for _ in range(200):
         s.offer(hot, np.array([1, 1, 1]))
-    assert len(s._heap) <= 4 * s.capacity
+    assert len(s) == 3
+    assert all(v.shape[0] == 3 for v in s.state_dict().values())
     assert s.counts()[(1,)] == 200
-    # eviction still finds the true minimum after compactions
     s.offer(_rows(4, 5, 6, 7, 8), np.ones(5))
+    assert len(s) == s.capacity
     s.offer(_rows(9), np.array([50]))
-    assert 9 in set(s.values()[:, 0].tolist())
-    assert {1, 2, 3} <= set(s.values()[:, 0].tolist())
+    # the minimum (count 1) goes, its tie broken by the lowest key
+    assert set(s.values()[:, 0].tolist()) == {1, 2, 3, 5, 6, 7, 8, 9}
+    assert s.counts()[(9,)] == 1 + 50
+
+
+def _zipf_rows(rng, n, n_cols):
+    """n rows of width n_cols, Zipf-distributed, weights 1-4."""
+    z = rng.zipf(1.1, size=n).astype(np.uint64) & np.uint64(0xFFFFFFFF)
+    cols = [(z * np.uint64(2654435761 * j + 1)) & np.uint64(0xFFFFFFFF)
+            for j in range(n_cols)]
+    rows = np.stack(cols, axis=1).astype(np.uint32)
+    return rows, rng.integers(1, 5, size=n)
+
+
+@pytest.mark.parametrize("n_cols", [1, 2, 3])
+@pytest.mark.parametrize("capacity", [8, 1 << 10, 1 << 16])
+def test_block_rule_keeps_the_space_saving_guarantees(capacity, n_cols):
+    """count >= true, count - true <= W/m, count - err <= true, and every
+    row with true > W/m kept, over blocks that evict."""
+    rng = np.random.default_rng(capacity + n_cols)
+    n = max(5 * capacity, 4096)
+    rows, freqs = _zipf_rows(rng, n, n_cols)
+    rows[::5] = rows[0]  # one row past W/m even at capacity 8
+    s = SpaceSaving(capacity=capacity, n_cols=n_cols)
+    for i in range(0, n, 4096):
+        s.offer(rows[i:i + 4096], freqs[i:i + 4096])
+    uniq, inv = np.unique(rows, axis=0, return_inverse=True)
+    true = np.bincount(inv.reshape(-1), weights=freqs)
+    assert uniq.shape[0] > capacity  # the pool had to evict
+    w_over_m = freqs.sum() / capacity
+    truth = dict(zip(map(tuple, uniq.tolist()), true.tolist()))
+    sd = s.state_dict()
+    assert len(s) == capacity
+    t = np.asarray([truth[r] for r in map(tuple, sd["rows"].tolist())])
+    assert np.all(sd["counts"] >= t)
+    assert np.all(sd["counts"] - t <= w_over_m)
+    assert np.all(sd["counts"] - sd["errs"] <= t)
+    heavy = {r for r, c in truth.items() if c > w_over_m}
+    assert heavy and heavy <= set(map(tuple, sd["rows"].tolist()))
+
+
+@pytest.mark.parametrize("n_cols", [1, 2, 3])
+def test_offer_is_merge_of_the_blocks_exact_summary(n_cols):
+    rng = np.random.default_rng(5)
+    rows, freqs = _zipf_rows(rng, 2000, n_cols)
+    freqs[::9] = 0  # zero-weight pad rows stay out on both paths
+    pool = SpaceSaving(capacity=64, n_cols=n_cols)
+    pool.offer(rows[:1500], freqs[:1500])
+    assert len(pool) == pool.capacity
+    block, bfreqs = rows[1500:], freqs[1500:]
+    # under capacity, so exact: floor 0, no row dropped
+    exact = SpaceSaving(capacity=block.shape[0] + 1, n_cols=n_cols)
+    exact.offer(block, bfreqs)
+    merged = SpaceSaving.fold([pool])
+    merged.merge_from(exact)
+    pool.offer(block, bfreqs)
+    for k, v in pool.state_dict().items():
+        np.testing.assert_array_equal(v, merged.state_dict()[k])
+
+
+def test_state_round_trip_is_bit_exact_after_evictions():
+    rng = np.random.default_rng(9)
+    rows, freqs = _zipf_rows(rng, 6000, 2)
+    freqs = freqs * 0.37  # fractional counts: bits, not just values
+    a = SpaceSaving(capacity=48, n_cols=2)
+    for i in range(0, 3000, 500):
+        a.offer(rows[i:i + 500], freqs[i:i + 500])
+    sd = a.state_dict()
+    b = SpaceSaving(capacity=48, n_cols=2)
+    b.load_state(**sd)
+    for s in (a, b):
+        for i in range(3000, 6000, 500):
+            s.offer(rows[i:i + 500], freqs[i:i + 500])
+    sa, sb = a.state_dict(), b.state_dict()
+    for k in sa:
+        assert sa[k].dtype == sb[k].dtype
+        np.testing.assert_array_equal(sa[k], sb[k])
+    # a restore in any row order lands in key order; repeats are refused
+    perm = rng.permutation(len(a))
+    c = SpaceSaving(capacity=48, n_cols=2)
+    c.load_state(sa["rows"][perm], sa["counts"][perm], sa["errs"][perm])
+    assert c.counts() == a.counts()
+    np.testing.assert_array_equal(c.values(), sa["rows"])
+    with pytest.raises(ValueError, match="repeats"):
+        c.load_state(sa["rows"][[0, 0]], sa["counts"][:2], sa["errs"][:2])
 
 
 def test_validation():
     with pytest.raises(ValueError):
         SpaceSaving(capacity=0, n_cols=1)
+    with pytest.raises(ValueError, match="n_cols"):
+        SpaceSaving(capacity=2, n_cols=0)
     s = SpaceSaving(capacity=2, n_cols=2)
     with pytest.raises(ValueError, match="\\[N, 2\\]"):
         s.offer(np.zeros((3, 1), np.uint32))
